@@ -43,6 +43,7 @@ from benchmarks import (cache_bench, ensemble_bench, fig3_job_status,  # noqa: F
                         stat_bench, table2_lemon, trace_bench)
 from benchmarks import common
 from benchmarks.common import all_benchmarks
+from repro.launch.compile_cache import configure_compile_cache
 
 
 _THROUGHPUT_SUFFIXES = ("jobs_per_sec", "cells_per_sec")
@@ -151,6 +152,7 @@ def main() -> None:
                          "vs a benchmarks.run --json file and exit "
                          "non-zero on a >20%% jobs/sec or cells/sec drop")
     args = ap.parse_args()
+    configure_compile_cache()
     common.QUICK = args.quick
     common.PROFILE = args.profile
     only = set(args.only.split(",")) if args.only else None

@@ -84,8 +84,10 @@ class CheckpointManager:
         for sync mode; just the host-snapshot time for async)."""
         t0 = time.time()
         flat = _flatten(tree)
-        # snapshot to host (device_get) — this is the blocking part
-        host = {k: _encode(jax.device_get(v)) for k, v in flat.items()}
+        # snapshot to host — this is the blocking part.  np.array copies:
+        # a device_get may alias the buffer (CPU backend), and the trainer
+        # donates these buffers to the next step while the write runs
+        host = {k: _encode(np.array(v)) for k, v in flat.items()}
         snapshot_s = time.time() - t0
         if self.async_mode:
             self.wait()  # one write in flight at a time

@@ -14,6 +14,7 @@ execute through it.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import os
 import time
@@ -371,6 +372,27 @@ def grid(gpus_list: Sequence[int], seeds: Sequence[int], *,
 # ---------------------------------------------------------------------------
 # worker-pool executor
 # ---------------------------------------------------------------------------
+# what every pool worker starts with (see ``run_cells``)
+WORKER_ENV = {"REPRO_STAT_BACKEND": "numpy", "JAX_PLATFORMS": "cpu"}
+
+
+@contextlib.contextmanager
+def _spawn_env(env: dict):
+    """Set ``env`` in ``os.environ`` while workers are spawned: a spawned
+    interpreter copies the parent's environment as it starts, before it
+    imports anything."""
+    saved = {k: os.environ.get(k) for k in env}
+    os.environ.update(env)
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+
+
 def _indexed_call(arg):
     worker, i, task = arg
     return i, worker(task)
@@ -388,13 +410,21 @@ def run_cells(worker: Callable, tasks: Sequence, *, procs: int = 0,
 
     spawn, not fork: the host process may carry jax's thread pools
     (benchmark suite, pytest), and forking a multithreaded process can
-    deadlock; workers only re-import the numpy-level sim stack."""
+    deadlock; workers only re-import the numpy-level sim stack.
+
+    Workers never start a JAX backend on the chip, whatever the parent's
+    environment holds: a parent that ran the ``jax_vmap`` stat backend
+    holds the chip, and a child reaching for it would fail or hang.  They
+    are spawned with :data:`WORKER_ENV` (numpy stat backend, JAX pinned to
+    the CPU)."""
     n = len(tasks)
     results: list = [None] * n
     if procs and procs > 1 and n > 1:
         import multiprocessing as mp
 
-        with mp.get_context("spawn").Pool(min(procs, n)) as pool:
+        with _spawn_env(WORKER_ENV):
+            pool = mp.get_context("spawn").Pool(min(procs, n))
+        with pool:
             it = pool.imap_unordered(
                 _indexed_call, [(worker, i, t) for i, t in enumerate(tasks)])
             for i, res in it:

@@ -17,6 +17,12 @@ TPU-native design (DESIGN.md §3: adapt, don't port):
 ``repro.kernels.ops.flash_attention``; the pure-jnp oracle lives in
 ``ref.py`` and the interpret=True equivalence tests in
 ``tests/test_kernels.py``.
+
+Backward pass: ``flash_attention`` carries an explicit ``custom_vjp``.  The
+forward is this Pallas kernel, which also emits the per-row log-sum-exp;
+the backward is *not* a Pallas kernel — it is the blockwise-jnp flash
+backward ``repro.kernels.ops._flash_bwd_impl``, which recomputes each
+block's probabilities from (q, k, lse) and never materializes S x S.
 """
 from __future__ import annotations
 
@@ -29,10 +35,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ops import _flash_bwd_impl
+
 NEG_INF = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
             scale: float, causal: bool, window: int, chunk: int,
             softcap: float, block_q: int, block_k: int, n_kv: int):
     qi = pl.program_id(2)
@@ -78,13 +86,13 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         if chunk > 0:
             mask &= (q_pos // chunk) == (k_pos // chunk)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
+        m_prev = m_scr[...]  # (bq, 1)
         l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_prev * corr + p.sum(axis=1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+        l_scr[...] = l_prev * corr + p.sum(axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -92,7 +100,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(kj == n_kv - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-20)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_scr[...] + jnp.log(l)
 
 
 def flash_attention(
@@ -108,6 +117,45 @@ def flash_attention(
     block_k: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
+    """Differentiable flash attention: Pallas forward, blockwise-jnp
+    backward (see the module docstring).  ``interpret`` is for tests."""
+    return _flash(q, k, v, causal, window, chunk, softcap, block_q, block_k,
+                  interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, causal, window, chunk, softcap, block_q, block_k,
+           interpret):
+    return _forward(q, k, v, causal, window, chunk, softcap, block_q,
+                    block_k, interpret)[0]
+
+
+def _flash_fwd(q, k, v, causal, window, chunk, softcap, block_q, block_k,
+               interpret):
+    o, lse = _forward(q, k, v, causal, window, chunk, softcap, block_q,
+                      block_k, interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_bwd(causal, window, chunk, softcap, block_q, block_k, interpret,
+               res, do):
+    q, k, v, o, lse = res
+    B, Sq, H, _ = q.shape
+    KV = k.shape[2]
+    # kernel lse is (B, H, Sq, 1), head h = kv * G + g
+    lse = lse.reshape(B, KV, H // KV, Sq)
+    return _flash_bwd_impl(q, k, v, o, lse, do, causal=causal, window=window,
+                           chunk=chunk, softcap=softcap, q_offset=0,
+                           block_q=block_q, block_k=block_k,
+                           seed_carries=bool(jax.typeof(q).vma))
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _forward(q, k, v, causal, window, chunk, softcap, block_q, block_k,
+             interpret):
+    """The Pallas kernel: returns (o (B, Sq, H, D), lse (B, H, Sq, 1))."""
     B, Sq, H, D = q.shape
     _, Sk, KV, _ = k.shape
     G = H // KV
@@ -128,7 +176,9 @@ def flash_attention(
         chunk=chunk, softcap=softcap, block_q=block_q, block_k=block_k,
         n_kv=nk)
 
-    out = pl.pallas_call(
+    # under shard_map the outputs vary over every axis an input varies over
+    vma = frozenset().union(*(jax.typeof(x).vma for x in (q, k, v)))
+    out, lse = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
@@ -139,17 +189,24 @@ def flash_attention(
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, h, i, j, G=G: (b, h // G, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, D),
-                               lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+        out_specs=[
+            pl.BlockSpec((1, 1, block_q, D),
+                         lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, i, j: (b, h, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32, vma=vma),
+        ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
     )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)
+    return out.transpose(0, 2, 1, 3), lse
 
 
 def vmem_bytes(block_q: int, block_k: int, d: int, dtype_bytes: int = 2) -> int:

@@ -26,11 +26,8 @@ NEG_INF = -1e30
 
 
 def use_pallas() -> bool:
-    forced = os.environ.get("REPRO_USE_PALLAS", "auto")
-    if forced == "1":
-        return True
-    if forced == "0":
-        return False
+    """The Pallas kernels run wherever the platform is a TPU, and only
+    there (other platforms take the jnp paths)."""
     return jax.default_backend() == "tpu"
 
 
@@ -68,11 +65,8 @@ def flash_attention(
     block_q = block_q or int(os.environ.get("REPRO_FLASH_BLOCK_Q", 1024))
     block_k = block_k or int(os.environ.get("REPRO_FLASH_BLOCK_K", 1024))
     if use_pallas() and q.shape[1] == k.shape[1] and q_offset == 0:
-        from repro.kernels import flash_attention as fak
-
-        return fak.flash_attention(
-            q, k, v, causal=causal, window=window, chunk=chunk, softcap=softcap
-        )
+        return pallas_flash(q, k, v, causal=causal, window=window,
+                            chunk=chunk, softcap=softcap)
     B, Sq, H, D = q.shape
     _, Sk, KV, _ = k.shape
     if Sq * Sk <= 1024 * 1024:  # tiny: the oracle is cheaper than blocking
@@ -90,6 +84,32 @@ def flash_attention(
                   block_q, block_k)
 
 
+def pallas_flash(q, k, v, *, interpret: bool = False, **kw) -> jax.Array:
+    """The Pallas flash kernel, under ``shard_map`` when a mesh context is
+    active: XLA cannot partition a ``pallas_call``, so each device runs the
+    kernel on its own batch shard, and on its own head shard when both the
+    q and the kv head counts divide the model axis (a kv head then stays
+    with its query group).  ``interpret`` is for tests."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels import flash_attention as fak
+    from repro.parallel import axes as paxes
+
+    call = functools.partial(fak.flash_attention, interpret=interpret, **kw)
+    mesh = paxes._CTX.mesh
+    if mesh is None:
+        return call(q, k, v)
+    batch_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
+    n_batch = int(np.prod([mesh.shape[a] for a in batch_axes]))
+    bspec = batch_axes if batch_axes and q.shape[0] % n_batch == 0 else None
+    n_model = mesh.shape.get("model", 1)
+    heads = n_model > 1 and q.shape[2] % n_model == 0 \
+        and k.shape[2] % n_model == 0
+    spec = P(bspec, None, "model" if heads else None, None)
+    return jax.shard_map(call, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec)(q, k, v)
+
+
 def _maybe_context_parallel(q, k, v, *, causal, window, chunk, softcap,
                             q_offset, block_q, block_k):
     """Context-parallel flash attention over the TP axis.
@@ -103,16 +123,10 @@ def _maybe_context_parallel(q, k, v, *, causal, window, chunk, softcap,
     the shard's global position.  dK/dV cotangents psum automatically via
     shard_map's replicated-input transpose.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.parallel import axes as paxes
 
-    if not hasattr(jax.lax, "pcast"):
-        # pre-vma shard_map can't type the kernel's device-varying scalar
-        # residual (q_offset) through the custom-vjp transpose — fall back
-        # to plain SPMD (replicated attention; correct, just not sharded)
-        return None
     mesh = paxes._CTX.mesh
     if mesh is None or "model" not in mesh.shape:
         return None
@@ -135,8 +149,9 @@ def _maybe_context_parallel(q, k, v, *, causal, window, chunk, softcap,
         return _flash_off(qs, ks, vs, off, causal, softcap,
                           min(block_q, s_local), block_k)
 
-    return shard_map(inner, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
-                     out_specs=q_spec)(q, k, v)
+    return jax.shard_map(inner, mesh=mesh,
+                         in_specs=(q_spec, kv_spec, kv_spec),
+                         out_specs=q_spec)(q, k, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))

@@ -26,16 +26,17 @@ def _kernel(x_ref, la_ref, h0_ref, o_ref, hn_ref, h_scr, *,
 
     @pl.when(c == 0)
     def _init():
-        h_scr[...] = h0_ref[0].astype(jnp.float32)
+        h_scr[...] = h0_ref[0]
 
     def step(t, _):
-        la = la_ref[0, t].astype(jnp.float32)   # (bw,)
-        x = x_ref[0, t].astype(jnp.float32)
+        # rows (1, bw) at a dynamic offset: f32 tiles (see ``rglru``)
+        la = la_ref[0, pl.ds(t, 1), :]
+        x = x_ref[0, pl.ds(t, 1), :]
         a = jnp.exp(la)
         b = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(2.0 * la), 1e-12)) * x
         h = a * h_scr[...] + b
         h_scr[...] = h
-        o_ref[0, t] = h.astype(o_ref.dtype)
+        o_ref[0, pl.ds(t, 1), :] = h
         return 0
 
     jax.lax.fori_loop(0, chunk, step, 0)
@@ -57,6 +58,12 @@ def rglru(
     B, S, W = x.shape
     if h0 is None:
         h0 = jnp.zeros((B, W), jnp.float32)
+    # f32 tiles: the step loop reads one row at a dynamic offset, which the
+    # TPU compiler accepts only for unpacked (32-bit) sublanes; h0/hn are
+    # (B, 1, W) so their blocks' last two dims are (1, block_w)
+    xf = x.astype(jnp.float32)
+    laf = log_a.astype(jnp.float32)
+    h0 = h0.astype(jnp.float32).reshape(B, 1, W)
     chunk = min(chunk, S)
     while S % chunk:
         chunk //= 2
@@ -76,20 +83,20 @@ def rglru(
                          lambda bw, c, n_w=n_w: (bw // n_w, c, bw % n_w)),
             pl.BlockSpec((1, chunk, block_w),
                          lambda bw, c, n_w=n_w: (bw // n_w, c, bw % n_w)),
-            pl.BlockSpec((1, block_w),
-                         lambda bw, c, n_w=n_w: (bw // n_w, bw % n_w)),
+            pl.BlockSpec((1, 1, block_w),
+                         lambda bw, c, n_w=n_w: (bw // n_w, 0, bw % n_w)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, block_w),
                          lambda bw, c, n_w=n_w: (bw // n_w, c, bw % n_w)),
-            pl.BlockSpec((1, block_w),
-                         lambda bw, c, n_w=n_w: (bw // n_w, bw % n_w)),
+            pl.BlockSpec((1, 1, block_w),
+                         lambda bw, c, n_w=n_w: (bw // n_w, 0, bw % n_w)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S, W), x.dtype),
-            jax.ShapeDtypeStruct((B, W), jnp.float32),
+            jax.ShapeDtypeStruct((B, S, W), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, W), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
         interpret=interpret,
-    )(x, log_a, h0)
-    return o, hn
+    )(xf, laf, h0)
+    return o.astype(x.dtype), hn.reshape(B, W)

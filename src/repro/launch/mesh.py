@@ -17,19 +17,9 @@ SINGLE_POD = (16, 16)
 MULTI_POD = (2, 16, 16)
 
 
-def compat_make_mesh(shape: tuple[int, ...],
-                     axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    """`jax.make_mesh` with Auto axis types where the jax version has them
-    (jax.sharding.AxisType appeared after 0.4.x; older versions only build
-    Auto meshes, which is what we want anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def _mk(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-_mk = compat_make_mesh
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

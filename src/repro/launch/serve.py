@@ -9,6 +9,7 @@ import argparse
 import json
 
 from repro.configs.base import get_arch, list_archs, smoke_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.runtime.fault_injection import FaultInjector
 from repro.runtime.serve_loop import ServeConfig, Server
 
@@ -23,6 +24,7 @@ def main() -> None:
     ap.add_argument("--inject-rate", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:

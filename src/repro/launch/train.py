@@ -19,6 +19,7 @@ import dataclasses
 import json
 
 from repro.configs.base import get_arch, list_archs, smoke_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.core.ettr_model import ETTRParams, expected_ettr
 from repro.runtime.fault_injection import FaultInjector
 from repro.runtime.train_loop import FaultTolerantTrainer, TrainerConfig
@@ -62,6 +63,7 @@ def main() -> None:
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:

@@ -48,6 +48,9 @@ def normal_init(key, shape, dtype, scale):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
+_normal_init_jit = jax.jit(normal_init, static_argnums=(1, 2, 3))
+
+
 def materialize(defs, seed: int = 0):
     """Initialize a pytree of ParamDef into concrete arrays."""
     leaves, treedef = jax.tree_util.tree_flatten(
@@ -64,7 +67,9 @@ def materialize(defs, seed: int = 0):
         elif d.init == "custom":
             arr = d.init_fn(key, d.shape, d.dtype)  # type: ignore[misc]
         else:
-            arr = normal_init(key, d.shape, d.dtype, d.init_scale)
+            # jitted so the f32 draw fuses into the cast: a large bf16 leaf
+            # never holds its f32 intermediates on the device
+            arr = _normal_init_jit(key, d.shape, d.dtype, d.init_scale)
         out.append(arr)
     return jax.tree_util.tree_unflatten(treedef, out)
 

@@ -436,6 +436,28 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int, enc_len: int = 0):
     return {"pos": jnp.zeros((), jnp.int32), "groups": groups}
 
 
+def extend_global_cache(cfg: ArchConfig, caches: list, length: int) -> list:
+    """Pad every global-attention layer's prefill K/V (stacked
+    (layers, B, S, KV, D)) to ``length`` slots; the empty slots are masked
+    by decode until it writes them.  Windowed and recurrent caches keep
+    their size."""
+    out = []
+    for (pattern, _), g in zip(cfg.block_groups, caches):
+        g = dict(g)
+        for i, kind in enumerate(pattern):
+            if kind != "global":
+                continue
+            ent = dict(g[f"p{i}"])
+            for name in ("k", "v"):
+                x = ent[name]
+                ent[name] = jnp.pad(
+                    x, ((0, 0), (0, 0), (0, length - x.shape[2]), (0, 0),
+                        (0, 0)))
+            g[f"p{i}"] = ent
+        out.append(g)
+    return out
+
+
 def cache_axes(cfg: ArchConfig):
     """Logical-axis pytree matching init_cache's structure."""
     kv = ("layers", "cache_batch", "cache_seq", "act_kv_heads", None)

@@ -19,7 +19,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 BLOCK = 256
@@ -75,4 +74,4 @@ def compressed_psum(x: jax.Array, mesh: Mesh, axis: str) -> jax.Array:
         return out
 
     spec = P()  # fully replicated view per shard; reduction over `axis`
-    return shard_map(inner, mesh=mesh, in_specs=spec, out_specs=spec)(x)
+    return jax.shard_map(inner, mesh=mesh, in_specs=spec, out_specs=spec)(x)

@@ -17,15 +17,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-
-def _pcast_varying(x, axis):
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, (axis,), to="varying")
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:
@@ -60,10 +52,9 @@ def pipeline_forward(
         state = jnp.zeros(mb_shape, x_local.dtype)  # in-flight activation
         outputs = jnp.zeros_like(x_local)
         # carries become device-varying inside the loop (stage_id use);
-        # mark them as such up front for shard_map's vma typing (a no-op on
-        # pre-vma jax, which has no jax.lax.pcast)
-        state = _pcast_varying(state, axis)
-        outputs = _pcast_varying(outputs, axis)
+        # mark them as such up front for shard_map's vma typing
+        state = jax.lax.pcast(state, (axis,), to="varying")
+        outputs = jax.lax.pcast(outputs, (axis,), to="varying")
 
         def step(carry, t):
             state, outputs = carry
@@ -87,7 +78,7 @@ def pipeline_forward(
         # only the last stage wrote outputs; replicate to all shards
         return jax.lax.psum(outputs, axis)
 
-    return shard_map(
+    return jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(P(axis), P()),

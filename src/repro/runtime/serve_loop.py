@@ -47,7 +47,8 @@ class Server:
         self.injector = injector or FaultInjector()
         defs = pmod.cast_defs(transformer.model_defs(cfg), jnp.bfloat16)
         self.params = pmod.materialize(defs, seed=scfg.seed)
-        self.prefill = jax.jit(make_prefill_step(cfg))
+        self.prefill = jax.jit(make_prefill_step(
+            cfg, cache_len=scfg.prompt_len + scfg.max_new_tokens))
         self.decode = jax.jit(make_decode_step(cfg))
 
     def _requests(self) -> np.ndarray:

@@ -62,6 +62,7 @@ class AttemptRecord:
     wall_s: float
     outcome: str              # completed | fault:<symptom>
     excluded_nodes: tuple = ()
+    restore_s: float = 0.0    # init or checkpoint restore, before step 1
 
 
 @dataclass
@@ -76,6 +77,7 @@ class TrainReport:
     final_step: int
     excluded_nodes: set
     lemon_verdicts: list
+    step_wall_s: list = field(default_factory=list)  # per step, in order
 
     @property
     def measured_ettr(self) -> float:
@@ -93,9 +95,11 @@ class FaultTolerantTrainer:
         self.defs = transformer.model_defs(cfg)
         opt_cfg = adamw.AdamWConfig(lr=tcfg.lr, warmup_steps=5,
                                     total_steps=max(tcfg.total_steps, 10))
+        # params and optimizer state are donated: each step updates them in
+        # place (a checkpoint copies them to the host before the next step)
         self.step_fn = jax.jit(make_train_step(
             cfg, opt_cfg, grad_compression=tcfg.grad_compression,
-            n_microbatches=tcfg.n_microbatches))
+            n_microbatches=tcfg.n_microbatches), donate_argnums=(0, 1))
         self.pipeline = SyntheticLMPipeline(DataConfig(
             vocab_size=cfg.vocab_size, seq_len=tcfg.seq_len,
             global_batch=tcfg.global_batch, seed=tcfg.seed))
@@ -117,13 +121,16 @@ class FaultTolerantTrainer:
         return params, opt_state
 
     def _restore_or_init(self):
-        template_p = pmod.abstract(self.defs)
-        params, opt_state = None, None
         start_step = 0
+        # an async write still in flight when the attempt failed is the
+        # newest checkpoint: its writer outlives the attempt, so let it land
+        self.manager.wait()
         if self.manager.latest_step() is not None:
-            p0, o0 = self._init_state()  # structures for the template
-            step, (params, opt_state), extra = self.manager.restore(
-                (p0, o0))
+            # shapes only: a materialized template would hold a second copy
+            # of the state on the device
+            template_p = pmod.abstract(self.defs)
+            template = (template_p, jax.eval_shape(adamw.init, template_p))
+            step, (params, opt_state), extra = self.manager.restore(template)
             params = jax.tree_util.tree_map(jax.numpy.asarray, params)
             opt_state = jax.tree_util.tree_map(jax.numpy.asarray, opt_state)
             start_step = int(extra.get("data_step", step))
@@ -167,7 +174,11 @@ class FaultTolerantTrainer:
             a_t0 = time.time()
             if tc.sim_u0_s:
                 time.sleep(tc.sim_u0_s)
+            # drop the failed attempt's state before restoring a new copy
+            params = opt_state = None
+            r_t0 = time.time()
             params, opt_state, step = self._restore_or_init()
+            restore_s = time.time() - r_t0
             restart_s += time.time() - a_t0
             last_ckpt_t = time.time()
             since_ckpt_wall = 0.0
@@ -216,7 +227,7 @@ class FaultTolerantTrainer:
                 lost_s += since_ckpt_wall  # work since last checkpoint
             attempts.append(AttemptRecord(
                 attempt_no, start_step, step, time.time() - a_t0, outcome,
-                tuple(sorted(self.excluded))))
+                tuple(sorted(self.excluded)), restore_s))
 
         self.manager.wait()
         lemon_verdicts = self.detector.scan(self.node_histories.values())
@@ -227,4 +238,5 @@ class FaultTolerantTrainer:
             productive_wall_s=productive, checkpoint_block_s=ckpt_block_s,
             restart_overhead_s=restart_s, lost_step_wall_s=lost_s,
             final_step=step, excluded_nodes=set(self.excluded),
-            lemon_verdicts=[v for v in lemon_verdicts if v.is_lemon])
+            lemon_verdicts=[v for v in lemon_verdicts if v.is_lemon],
+            step_wall_s=step_walls)

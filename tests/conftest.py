@@ -10,14 +10,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-try:
-    from hypothesis import HealthCheck, settings
-except ModuleNotFoundError:  # container image has no hypothesis
-    sys.path.insert(0, os.path.dirname(__file__))
-    from _hypothesis_fallback import install
-
-    install()
-    from hypothesis import HealthCheck, settings
+from hypothesis import HealthCheck, settings  # noqa: E402
 
 settings.register_profile(
     "repro",

@@ -1,7 +1,7 @@
 """Context-parallel flash attention (shard_map over the TP axis)."""
 import textwrap
 
-from conftest import run_subprocess_py
+from tests.conftest import run_subprocess_py
 
 
 def test_cp_flash_matches_oracle_fwd_and_grads():
@@ -13,8 +13,8 @@ def test_cp_flash_matches_oracle_fwd_and_grads():
         from repro.kernels import ops, ref
         from repro.parallel.axes import mesh_context, TRAIN_RULES
 
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         B, S, H, KV, D = 2, 2048, 6, 2, 64  # H=6 % 4 != 0 -> CP path
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
         q = jax.random.normal(ks[0], (B,S,H,D), jnp.float32)

@@ -35,6 +35,32 @@ def test_run_cells_serial_order_and_streaming(quick_stats):
     assert seen == [(0, 10), (1, 20), (2, 30)]
 
 
+def _backend_probe(_task):
+    """Pool worker: the stat backend it resolves and whether anything in it
+    started a JAX backend."""
+    from repro.core.backend import get_backend
+
+    name = get_backend().name
+    started = False
+    if "jax" in sys.modules:
+        from jax._src import xla_bridge
+
+        started = bool(xla_bridge._backends)
+    return (name, os.environ.get("JAX_PLATFORMS"), started)
+
+
+def test_pool_workers_never_start_a_jax_backend(monkeypatch):
+    """One process per chip: a parent environment asking for the jax stat
+    backend (and the chip) does not reach the spawn-pool workers, and the
+    parent's environment is left as it was."""
+    monkeypatch.setenv("REPRO_STAT_BACKEND", "jax_vmap")
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    out = run_cells(_backend_probe, [0, 1], procs=2)
+    assert out == [("NUMPY", "cpu", False)] * 2
+    assert os.environ["REPRO_STAT_BACKEND"] == "jax_vmap"
+    assert os.environ["JAX_PLATFORMS"] == "tpu"
+
+
 def test_run_cells_pool_matches_serial(quick_stats):
     """Spawn-pool results are per-cell identical to the serial run (modulo
     wall-clock) and arrive in task order in the returned list."""

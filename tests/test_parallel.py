@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 from hypothesis import given, strategies as st
 
-from conftest import run_subprocess_py
+from tests.conftest import run_subprocess_py
 from repro.parallel import compression
 from repro.parallel.axes import (LONG_CONTEXT_RULES, SERVE_RULES, TRAIN_RULES,
                                  ShardingRules)
@@ -31,8 +31,8 @@ def test_spec_for_divisibility(monkeypatch):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
         from repro.parallel.axes import TRAIN_RULES, spec_for
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         # kv_heads=1 cannot shard over model=4 -> None
         s1 = spec_for((1024, 1, 128), ("embed", "kv_heads", "head_dim"),
                       mesh, TRAIN_RULES)
@@ -75,8 +75,8 @@ def test_compressed_psum_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp, numpy as np
         from repro.parallel.compression import compressed_psum
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((4,), ("data",))
+        mesh = jax.make_mesh((4,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         x = jnp.linspace(-1, 1, 512, dtype=jnp.float32)
         out = compressed_psum(x, mesh, "data")
         want = 4.0 * x
@@ -99,8 +99,8 @@ def test_pipeline_forward_matches_sequential_subprocess():
         key = jax.random.PRNGKey(0)
         w = jax.random.normal(key, (n_stages, layers_per, d, d)) * 0.3
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 2, d))  # 8 microbatches
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((4,), ("stage",))
+        mesh = jax.make_mesh((4,), ("stage",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         def layer_fn(wi, h):
             return jnp.tanh(h @ wi)
         got = pipeline_forward(layer_fn, w, x, mesh)

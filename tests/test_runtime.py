@@ -65,6 +65,31 @@ def test_faulty_run_matches_clean_run_bit_exact(cfg, tmp_path):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_restore_waits_for_an_async_write_in_flight(cfg, tmp_path,
+                                                    monkeypatch):
+    """A fault while the last async checkpoint is still being written
+    resumes from that checkpoint, not from an older one or from scratch."""
+    import time
+
+    from repro.checkpoint.manager import CheckpointManager
+
+    write = CheckpointManager._write
+
+    def slow_write(self, *a):
+        time.sleep(0.5)
+        write(self, *a)
+
+    monkeypatch.setattr(CheckpointManager, "_write", slow_write)
+    tcfg = TrainerConfig(total_steps=6, global_batch=4, seq_len=32,
+                         ckpt_dir=str(tmp_path), ckpt_every_steps=2,
+                         ckpt_async=True, n_nodes=4, seed=0)
+    rep = FaultTolerantTrainer(cfg, tcfg, FaultInjector(schedule={
+        3: InjectedFault("gpu_memory_errors", node_id=0)})).run()
+    assert [a.start_step for a in rep.attempts] == [0, 2]
+    assert rep.final_step == 6
+    assert rep.losses[2] == rep.losses[3]  # step 2, before and after
+
+
 def test_loss_decreases(cfg, tmp_path):
     _, rep = _train(cfg, tmp_path / "l", steps=30)
     assert np.mean(rep.losses[-5:]) < np.mean(rep.losses[:5])
@@ -228,3 +253,38 @@ def test_server_output_deterministic(cfg):
                 FaultInjector(schedule={3: InjectedFault("pcie_errors")})).run()
     # a mid-decode fault + full replay must yield identical tokens
     assert np.array_equal(r1.outputs, r2.outputs)
+
+
+def test_decode_matches_full_forward_past_the_prompt():
+    """Decoding beyond the prompt keeps every earlier token in the global
+    KV cache: each decode step's logits equal a full forward pass over the
+    prompt plus the tokens generated so far (float32, own process)."""
+    import textwrap
+
+    from tests.conftest import run_subprocess_py
+
+    code = textwrap.dedent("""
+        import os
+        os.environ["REPRO_COMPUTE_DTYPE"] = "float32"
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.configs.base import get_arch, smoke_config
+        from repro.models import params as pmod, transformer
+        from repro.models.steps import make_decode_step, make_prefill_step
+        cfg = smoke_config(get_arch("starcoder2-3b"))
+        params = pmod.materialize(transformer.model_defs(cfg), seed=0)
+        S, N = 16, 6
+        toks = jax.random.randint(jax.random.PRNGKey(1), (2, S), 3,
+                                  cfg.vocab_size)
+        logits, cache = jax.jit(make_prefill_step(cfg, S + N))(
+            params, {"tokens": toks})
+        decode = jax.jit(make_decode_step(cfg))
+        for i in range(N):
+            tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+            toks = jnp.concatenate([toks, tok], axis=1)
+            logits, cache = decode(params, cache, tok)
+            full, _ = make_prefill_step(cfg)(params, {"tokens": toks})
+            np.testing.assert_allclose(logits, full, atol=1e-4, rtol=1e-4)
+        print("OK")
+    """)
+    r = run_subprocess_py(code, timeout=600)
+    assert "OK" in r.stdout, r.stderr[-3000:]

@@ -6,7 +6,7 @@ import textwrap
 import numpy as np
 import pytest
 
-from conftest import run_subprocess_py
+from tests.conftest import run_subprocess_py
 from repro.cluster import analysis
 from repro.cluster.scheduler import ClusterSim
 from repro.cluster.workload import ClusterSpec
