@@ -33,6 +33,8 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
+from repro.obs.spans import span
+
 _BF16 = "bfloat16"
 
 
@@ -82,48 +84,49 @@ class CheckpointManager:
     def save(self, step: int, tree, extra: Optional[dict] = None) -> float:
         """Returns the time the *step loop* was blocked (the paper's w_cp
         for sync mode; just the host-snapshot time for async)."""
-        t0 = time.time()
-        flat = _flatten(tree)
-        # snapshot to host — this is the blocking part.  np.array copies:
-        # a device_get may alias the buffer (CPU backend), and the trainer
-        # donates these buffers to the next step while the write runs
-        host = {k: _encode(np.array(v)) for k, v in flat.items()}
-        snapshot_s = time.time() - t0
+        with span("repro.ckpt.snapshot", step=step) as snapshot:
+            flat = _flatten(tree)
+            # snapshot to host — this is the blocking part.  np.array
+            # copies: a device_get may alias the buffer (CPU backend), and
+            # the trainer donates these buffers to the next step while the
+            # write runs
+            host = {k: _encode(np.array(v)) for k, v in flat.items()}
         if self.async_mode:
             self.wait()  # one write in flight at a time
             self._thread = threading.Thread(
                 target=self._write, args=(step, host, extra or {}),
                 daemon=True)
             self._thread.start()
-            return snapshot_s
-        self._write(step, host, extra or {})
-        return time.time() - t0
+            return snapshot.seconds
+        return snapshot.seconds + self._write(step, host, extra or {})
 
-    def _write(self, step: int, host: dict, extra: dict) -> None:
+    def _write(self, step: int, host: dict, extra: dict) -> float:
+        """Writes one checkpoint; returns the seconds it took."""
+        write = span("repro.ckpt.write", step=step)
         try:
-            t0 = time.time()
-            final = self.dir / f"step_{step:09d}"
-            tmp = self.dir / f".tmp-step_{step:09d}"
-            if tmp.exists():
-                shutil.rmtree(tmp)
-            tmp.mkdir(parents=True)
-            arrays = {k: v for k, (v, _) in host.items()}
-            np.savez(tmp / "arrays.npz", **arrays)
-            manifest = {
-                "step": step,
-                "dtypes": {k: d for k, (_, d) in host.items()},
-                "extra": extra,
-                "written_at": time.time(),
-            }
-            (tmp / "manifest.json").write_text(json.dumps(manifest))
-            if final.exists():
-                shutil.rmtree(final)
-            os.rename(tmp, final)  # atomicity boundary
-            self.write_log.append(CheckpointInfo(step, final,
-                                                 time.time() - t0))
+            with write:
+                final = self.dir / f"step_{step:09d}"
+                tmp = self.dir / f".tmp-step_{step:09d}"
+                if tmp.exists():
+                    shutil.rmtree(tmp)
+                tmp.mkdir(parents=True)
+                arrays = {k: v for k, (v, _) in host.items()}
+                np.savez(tmp / "arrays.npz", **arrays)
+                manifest = {
+                    "step": step,
+                    "dtypes": {k: d for k, (_, d) in host.items()},
+                    "extra": extra,
+                    "written_at": time.time(),
+                }
+                (tmp / "manifest.json").write_text(json.dumps(manifest))
+                if final.exists():
+                    shutil.rmtree(final)
+                os.rename(tmp, final)  # atomicity boundary
+            self.write_log.append(CheckpointInfo(step, final, write.seconds))
             self._gc()
         except BaseException as e:  # surfaced on next wait()/save()
             self._last_error = e
+        return write.seconds
 
     def wait(self) -> None:
         if self._thread is not None:

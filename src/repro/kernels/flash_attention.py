@@ -180,6 +180,7 @@ def _forward(q, k, v, causal, window, chunk, softcap, block_q, block_k,
     vma = frozenset().union(*(jax.typeof(x).vma for x in (q, k, v)))
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D),

@@ -77,6 +77,7 @@ def rglru(
     # grid: (batch * width-blocks) parallel, time sequential (minor)
     o, hn = pl.pallas_call(
         kernel,
+        name="rglru_scan",
         grid=(B * n_w, n_chunks),
         in_specs=[
             pl.BlockSpec((1, chunk, block_w),

@@ -84,6 +84,7 @@ def wkv6(
     kernel = functools.partial(_kernel, chunk=chunk, n_chunks=n_chunks)
     o, s_final = pl.pallas_call(
         kernel,
+        name="wkv6_scan",
         grid=(B, H, n_chunks),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, D), lambda b, h, c: (b, h, c, 0)),

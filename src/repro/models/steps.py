@@ -28,8 +28,15 @@ def make_train_step(cfg: ArchConfig, opt: adamw.AdamWConfig,
     """
 
     def grads_of(params, batch):
-        return jax.value_and_grad(
-            transformer.loss_fn, has_aux=True)(params, cfg, batch)
+        # value_and_grad's own vjp, split so that the device trace names
+        # the forward's ops apart from the backward's
+        with jax.named_scope("forward"):
+            loss, backward, metrics = jax.vjp(
+                lambda p: transformer.loss_fn(p, cfg, batch), params,
+                has_aux=True)
+        with jax.named_scope("backward"):
+            grads, = backward(jnp.ones_like(loss))
+        return (loss, metrics), grads
 
     def train_step(params, opt_state, batch):
         if n_microbatches <= 1:
@@ -60,7 +67,9 @@ def make_train_step(cfg: ArchConfig, opt: adamw.AdamWConfig,
         if grad_compression:
             grads = compression.compress_tree(grads, method=grad_compression)
         apply_fn = adamw.apply_8bit if use_8bit else adamw.apply
-        params, opt_state, opt_metrics = apply_fn(opt, params, opt_state, grads)
+        with jax.named_scope("optimizer"):
+            params, opt_state, opt_metrics = apply_fn(opt, params, opt_state,
+                                                      grads)
         metrics = dict(metrics, **opt_metrics)
         return params, opt_state, metrics
 

@@ -1,7 +1,7 @@
 """Live observability layer: streaming run metrics, worker heartbeats,
-and an engine self-profiler.
+an engine self-profiler, and the runtime's spans.
 
-Three pieces, one contract (see docs/observability.md):
+Four pieces (see docs/observability.md):
 
 * :class:`repro.obs.metrics.MetricsRegistry` — online counters / gauges
   / windowed statistics attached to ``ClusterSim`` via the same
@@ -18,6 +18,10 @@ Three pieces, one contract (see docs/observability.md):
 * :class:`repro.obs.profiler.EngineProfiler` — engine phase timers
   (event-loop breakdown: sched passes, fault handling, allocation,
   record appends) exposed as a self-profiling summary.
+* :mod:`repro.obs.spans` — ``span(name, **ids)``, the trainer's,
+  checkpoint manager's and server's host spans on the profiler's clock,
+  kept in process only while a profiler capture runs, plus
+  ``repro.compile`` records from ``jax.monitoring``.
 
 Front door for recorded snapshot streams::
 

@@ -7,7 +7,6 @@ the node, and replays the requests (serving "checkpoint" = the request
 queue itself; decode state is cheap to rebuild relative to training)."""
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -19,6 +18,7 @@ from repro.configs.base import ArchConfig
 from repro.models import params as pmod
 from repro.models import transformer
 from repro.models.steps import make_decode_step, make_prefill_step
+from repro.obs.spans import span
 from repro.runtime.fault_injection import FaultInjector, SimulatedFault
 
 
@@ -50,6 +50,7 @@ class Server:
         self.prefill = jax.jit(make_prefill_step(
             cfg, cache_len=scfg.prompt_len + scfg.max_new_tokens))
         self.decode = jax.jit(make_decode_step(cfg))
+        self.batches = 0  # calls of run(): the spans' ``batch`` id
 
     def _requests(self) -> np.ndarray:
         rng = np.random.default_rng(self.scfg.seed)
@@ -59,35 +60,40 @@ class Server:
 
     def run(self) -> ServeReport:
         sc = self.scfg
-        t0 = time.time()
-        prompts = self._requests()
-        retries = 0
-        step_counter = 0
-        while True:
-            try:
-                batch = {"tokens": jnp.asarray(prompts)}
-                if self.cfg.enc_dec:
-                    batch["frames"] = jnp.zeros(
-                        (sc.batch, sc.prompt_len, self.cfg.d_model),
-                        jnp.bfloat16)
-                logits, cache = self.prefill(self.params, batch)
-                out = np.zeros((sc.batch, sc.max_new_tokens), np.int32)
-                tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-                for i in range(sc.max_new_tokens):
-                    fault = self.injector.poll(step_counter)
-                    step_counter += 1
-                    if fault is not None and fault.kind == "crash":
-                        raise SimulatedFault(fault)
-                    out[:, i] = np.asarray(tok)
-                    logits, cache = self.decode(
-                        self.params, cache, tok[:, None])
+        b = self.batches
+        self.batches += 1
+        with span("repro.serve.run", batch=b) as whole:
+            prompts = self._requests()
+            retries = 0
+            step_counter = 0
+            while True:
+                try:
+                    batch = {"tokens": jnp.asarray(prompts)}
+                    if self.cfg.enc_dec:
+                        batch["frames"] = jnp.zeros(
+                            (sc.batch, sc.prompt_len, self.cfg.d_model),
+                            jnp.bfloat16)
+                    logits, cache = self.prefill(self.params, batch)
+                    out = np.zeros((sc.batch, sc.max_new_tokens), np.int32)
                     tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-                break
-            except SimulatedFault:
-                retries += 1
-                if retries > 8:
-                    raise
+                    for i in range(sc.max_new_tokens):
+                        fault = self.injector.poll(step_counter)
+                        step_counter += 1
+                        if fault is not None and fault.kind == "crash":
+                            raise SimulatedFault(fault)
+                        with span("repro.serve.copy", batch=b, token=i):
+                            out[:, i] = np.asarray(tok)
+                        with span("repro.serve.dispatch", batch=b, token=i):
+                            logits, cache = self.decode(
+                                self.params, cache, tok[:, None])
+                        tok = jnp.argmax(logits[:, -1],
+                                         axis=-1).astype(jnp.int32)
+                    break
+                except SimulatedFault:
+                    retries += 1
+                    if retries > 8:
+                        raise
         return ServeReport(
             completed_requests=sc.batch, retries=retries,
             tokens_generated=int(sc.batch * sc.max_new_tokens),
-            wall_s=time.time() - t0, outputs=out)
+            wall_s=whole.seconds, outputs=out)

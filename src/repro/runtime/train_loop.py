@@ -31,9 +31,9 @@ from repro.data.pipeline import DataConfig, SyntheticLMPipeline
 from repro.models import params as pmod
 from repro.models import transformer
 from repro.models.steps import make_train_step
+from repro.obs.spans import span
 from repro.optim import adamw
 from repro.runtime.fault_injection import FaultInjector, SimulatedFault
-from repro.runtime.monitor import StragglerMonitor
 
 
 @dataclass
@@ -112,7 +112,6 @@ class FaultTolerantTrainer:
                                for i in range(tcfg.n_nodes)}
         self.detector = LemonDetector()
         self.excluded: set[int] = set()
-        self.stragglers = StragglerMonitor(tcfg.n_nodes)
 
     # ------------------------------------------------------------------
     def _init_state(self):
@@ -160,7 +159,7 @@ class FaultTolerantTrainer:
         tc = self.tcfg
         attempts: list[AttemptRecord] = []
         losses: list[float] = []
-        run_t0 = time.time()
+        run_t0 = time.perf_counter()
         ckpt_block_s = 0.0
         restart_s = 0.0
         lost_s = 0.0
@@ -171,16 +170,15 @@ class FaultTolerantTrainer:
 
         while step < tc.total_steps and attempt_no < tc.max_attempts:
             attempt_no += 1
-            a_t0 = time.time()
+            a_t0 = time.perf_counter()
             if tc.sim_u0_s:
                 time.sleep(tc.sim_u0_s)
             # drop the failed attempt's state before restoring a new copy
             params = opt_state = None
-            r_t0 = time.time()
-            params, opt_state, step = self._restore_or_init()
-            restore_s = time.time() - r_t0
-            restart_s += time.time() - a_t0
-            last_ckpt_t = time.time()
+            with span("repro.train.restore", attempt=attempt_no) as restore:
+                params, opt_state, step = self._restore_or_init()
+            restart_s += time.perf_counter() - a_t0
+            last_ckpt_t = time.perf_counter()
             since_ckpt_wall = 0.0
             outcome = "completed"
             start_step = step
@@ -189,49 +187,51 @@ class FaultTolerantTrainer:
                     fault = self.injector.poll(step)
                     if fault is not None and fault.kind == "crash":
                         raise SimulatedFault(fault)
-                    s_t0 = time.time()
-                    batch = self.pipeline.next_batch()
-                    batch = {k: jax.numpy.asarray(v)
-                             for k, v in batch.items()}
-                    if fault is not None and fault.kind == "straggler":
-                        time.sleep(fault.slowdown * 0.01)
-                    params, opt_state, metrics = self.step_fn(
-                        params, opt_state, batch)
-                    loss = float(metrics["loss"])
+                    # ``step`` ids count from 0: the step that consumes
+                    # batch ``step`` of the pipeline
+                    with span("repro.train.step", attempt=attempt_no,
+                              step=step) as one:
+                        with span("repro.train.data", step=step):
+                            batch = self.pipeline.next_batch()
+                            batch = {k: jax.numpy.asarray(v)
+                                     for k, v in batch.items()}
+                        if fault is not None and fault.kind == "straggler":
+                            time.sleep(fault.slowdown * 0.01)
+                        with span("repro.train.dispatch", step=step):
+                            params, opt_state, metrics = self.step_fn(
+                                params, opt_state, batch)
+                        with span("repro.train.sync", step=step):
+                            loss = float(metrics["loss"])
                     losses.append(loss)
                     step += 1
-                    wall = time.time() - s_t0
-                    step_walls.append(wall)
-                    since_ckpt_wall += wall
-                    # straggler observation (uniform nodes + injected slow one)
-                    times = {i: wall for i in range(tc.n_nodes)}
-                    if fault is not None and fault.kind == "straggler":
-                        times[fault.node_id] = wall * fault.slowdown
-                    self.stragglers.observe(step, times)
+                    step_walls.append(one.seconds)
+                    since_ckpt_wall += one.seconds
                     save_now = (
                         (tc.ckpt_every_steps and
                          step % tc.ckpt_every_steps == 0)
                         or (not tc.ckpt_every_steps and
-                            self.policy.should_save(last_ckpt_t, time.time()))
+                            self.policy.should_save(last_ckpt_t,
+                                                    time.perf_counter()))
                         or step == tc.total_steps)
                     if save_now:
-                        blocked = self.manager.save(
-                            step, (params, opt_state),
-                            extra={"data_step": step})
-                        ckpt_block_s += blocked
-                        last_ckpt_t = time.time()
+                        # the checkpoint's step: the steps it holds
+                        with span("repro.train.save", step=step) as save:
+                            self.manager.save(step, (params, opt_state),
+                                              extra={"data_step": step})
+                        ckpt_block_s += save.seconds
+                        last_ckpt_t = time.perf_counter()
                         since_ckpt_wall = 0.0
             except SimulatedFault as e:
                 outcome = f"fault:{e.fault.symptom}"
                 self._handle_fault(e.fault, step)
                 lost_s += since_ckpt_wall  # work since last checkpoint
             attempts.append(AttemptRecord(
-                attempt_no, start_step, step, time.time() - a_t0, outcome,
-                tuple(sorted(self.excluded)), restore_s))
+                attempt_no, start_step, step, time.perf_counter() - a_t0,
+                outcome, tuple(sorted(self.excluded)), restore.seconds))
 
         self.manager.wait()
         lemon_verdicts = self.detector.scan(self.node_histories.values())
-        total_wall = time.time() - run_t0
+        total_wall = time.perf_counter() - run_t0
         productive = max(total_wall - ckpt_block_s - restart_s - lost_s, 0.0)
         return TrainReport(
             attempts=attempts, losses=losses, total_wall_s=total_wall,
