@@ -81,11 +81,15 @@ class Server:
                         step_counter += 1
                         if fault is not None and fault.kind == "crash":
                             raise SimulatedFault(fault)
-                        with span("repro.serve.copy", batch=b, token=i):
-                            out[:, i] = np.asarray(tok)
-                        with span("repro.serve.dispatch", batch=b, token=i):
+                        # the step that consumes token i is queued before
+                        # the host waits for token i, so the device runs
+                        # from one step straight into the next
+                        with span("repro.serve.dispatch", batch=b, token=i,
+                                  ahead=not tok.is_ready()):
                             logits, cache = self.decode(
                                 self.params, cache, tok[:, None])
+                        with span("repro.serve.copy", batch=b, token=i):
+                            out[:, i] = np.asarray(tok)
                         tok = jnp.argmax(logits[:, -1],
                                          axis=-1).astype(jnp.int32)
                     break

@@ -255,6 +255,58 @@ def test_server_output_deterministic(cfg):
     assert np.array_equal(r1.outputs, r2.outputs)
 
 
+def _lockstep(srv):
+    """The served tokens of ``srv``'s batch by the plain loop: copy token
+    i to the host, then dispatch the decode step that consumes it."""
+    sc = srv.scfg
+    logits, cache = srv.prefill(srv.params,
+                                {"tokens": jnp.asarray(srv._requests())})
+    out = np.zeros((sc.batch, sc.max_new_tokens), np.int32)
+    tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+    for i in range(sc.max_new_tokens):
+        out[:, i] = np.asarray(tok)
+        logits, cache = srv.decode(srv.params, cache, tok[:, None])
+        tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+    return out
+
+
+def test_server_outputs_equal_the_lockstep_loop(cfg):
+    srv = Server(cfg, ServeConfig(batch=2, prompt_len=16, max_new_tokens=6))
+    first, second = srv.run().outputs, srv.run().outputs
+    want = _lockstep(srv)
+    assert first.dtype == want.dtype == np.int32
+    assert np.array_equal(first, want) and np.array_equal(second, want)
+
+
+@pytest.mark.parametrize("crash_at", [None, 3])
+def test_server_decodes_max_new_tokens_per_attempt(cfg, crash_at):
+    """Counted through attribute wrappers, as the benchmark's serve
+    loop stamps each call's entry: every attempt is one prefill and then
+    one decode call a token, up to the crash or to ``max_new_tokens``."""
+    T = 6
+    schedule = {} if crash_at is None else {
+        crash_at: InjectedFault("ib_link_error")}
+    srv = Server(cfg, ServeConfig(batch=2, prompt_len=16, max_new_tokens=T),
+                 FaultInjector(schedule=schedule))
+    calls = []
+
+    def counted(name, fn):
+        def call(*args):
+            calls.append(name)
+            return fn(*args)
+        return call
+
+    srv.prefill = counted("prefill", srv.prefill)
+    srv.decode = counted("decode", srv.decode)
+    rep = srv.run()
+    crashed = [] if crash_at is None else ["prefill"] + ["decode"] * crash_at
+    assert calls == crashed + ["prefill"] + ["decode"] * T
+    assert rep.retries == (crash_at is not None)
+    calls.clear()
+    srv.run()  # the injector's polls go on counting; no fault is left
+    assert calls == ["prefill"] + ["decode"] * T
+
+
 def test_decode_matches_full_forward_past_the_prompt():
     """Decoding beyond the prompt keeps every earlier token in the global
     KV cache: each decode step's logits equal a full forward pass over the

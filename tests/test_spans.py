@@ -101,9 +101,16 @@ def test_server_spans_nest_and_feed_the_report(runs):
     serve = [(r.name, r.ids, r.parent) for r in runs.records
              if r.name.startswith("repro.serve.")]
     batch = 1  # the server's second run() call
-    tokens = [(name, {"batch": batch, "token": i}, "repro.serve.run")
-              for i in range(2)
-              for name in ("repro.serve.copy", "repro.serve.dispatch")]
+    # the step that consumes token i is dispatched before token i's copy;
+    # whether its input was still being computed (``ahead``) depends on
+    # how fast the device ran, so only its type is checked
+    ahead = [r.ids.get("ahead") for r in runs.records
+             if r.name == "repro.serve.dispatch"]
+    assert [type(a) for a in ahead] == [bool, bool]
+    tokens = [entry for i in range(2) for entry in (
+        ("repro.serve.dispatch", {"batch": batch, "token": i,
+                                  "ahead": ahead[i]}, "repro.serve.run"),
+        ("repro.serve.copy", {"batch": batch, "token": i}, "repro.serve.run"))]
     assert serve == tokens + [("repro.serve.run", {"batch": batch}, None)]
     whole, = [r for r in runs.records if r.name == "repro.serve.run"]
     assert runs.served.wall_s == whole.seconds
