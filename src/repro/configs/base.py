@@ -32,7 +32,10 @@ class MoESpec:
 
     n_experts: int
     top_k: int
-    capacity_factor: float = 1.25
+    # Expert slots per group are top_k * group / n_experts times this, and
+    # routes past them are dropped; None routes every token to its top_k
+    # experts (dropless: sorted routes through a grouped matmul).
+    capacity_factor: Optional[float] = 1.25
     # Dense FFN run in parallel with the routed experts (llama4-style).
     shared_expert: bool = False
     # Tokens are routed within groups of this size; dispatch/combine einsum
@@ -40,6 +43,26 @@ class MoESpec:
     group_size: int = 1024
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
+
+    @property
+    def dropless(self) -> bool:
+        return self.capacity_factor is None
+
+
+@dataclass(frozen=True)
+class YaRNSpec:
+    """YaRN rotary scaling (arXiv:2309.00071), as transformers'
+    ``_compute_yarn_parameters`` computes it: each frequency is blended
+    between its original and its ``factor``-interpolated value by a ramp
+    over the dimensions whose wavelengths lie between ``beta_fast`` and
+    ``beta_slow`` rotations of ``original_max_position``; cos and sin are
+    scaled by ``attention_factor``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -101,6 +124,8 @@ class ArchConfig:
     window: int = 0  # local / sliding / chunk width (0 = unused)
     qk_norm: bool = False
     rope_theta: float = 10_000.0
+    # YaRN on the global layers; local and chunked layers keep plain RoPE
+    yarn: Optional[YaRNSpec] = None
     attn_logit_softcap: float = 0.0
 
     # Sub-family specs.
@@ -299,6 +324,7 @@ def _ensure_loaded() -> None:
         "rwkv6_7b",
         "llama4_scout_17b_a16e",
         "mixtral_8x22b",
+        "mellum2_12b",
         "llava_next_34b",
         "rsc_llm",
     ):
@@ -323,8 +349,10 @@ def smoke_config(cfg: ArchConfig) -> ArchConfig:
     n_layers = sum(len(p) * r for p, r in groups)
     moe = None
     if cfg.moe is not None:
+        n_experts = min(cfg.moe.n_experts, 4)
         moe = dataclasses.replace(
-            cfg.moe, n_experts=min(cfg.moe.n_experts, 4), group_size=64
+            cfg.moe, n_experts=n_experts, group_size=64,
+            top_k=min(cfg.moe.top_k, n_experts // 2),
         )
     rglru = None
     if cfg.rglru is not None:

@@ -406,6 +406,49 @@ def decode_attention(
 
 
 # ---------------------------------------------------------------------------
+# Grouped matmul (the dropless expert layer)
+# ---------------------------------------------------------------------------
+def grouped_matmul(x: jax.Array, w: jax.Array,
+                   group_sizes: jax.Array) -> jax.Array:
+    """x (m, k) rows sorted by group, w (g, k, n), group_sizes (g,) int32
+    summing to m: row r of group i times w[i], (m, n) in x's dtype with
+    float32 accumulation.  A group with no rows reads none of its weights."""
+    if use_pallas():
+        return pallas_gmm(x, w, group_sizes)
+    return jax.lax.ragged_dot(x, w, group_sizes,
+                              preferred_element_type=jnp.float32).astype(
+                                  x.dtype)
+
+
+def _gmm_tile(dim: int, cap: int = 1152) -> int:
+    """The largest multiple of 128 that divides ``dim`` and is at most
+    ``cap``, else ``dim`` whole (a block as wide as its array is legal)."""
+    for t in range(min(cap, dim) // 128 * 128, 0, -128):
+        if dim % t == 0:
+            return t
+    return dim
+
+
+def pallas_gmm(x, w, group_sizes, *, interpret: bool = False) -> jax.Array:
+    """The Pallas grouped matmul of the installed JAX (megablox ``gmm``).
+    Row tiles of 512 (fewer rows: one tile of them, padded to 16, the bf16
+    sublane tile); k and n tiles that divide them, at most 1152 wide.
+    Padding rows belong to no group and are cut off again."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    m, k = x.shape
+    n = w.shape[2]
+    tm = 512 if m >= 512 else -(-m // 16) * 16
+    pad = -m % tm
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    out = megablox.gmm(x, w, group_sizes, x.dtype,
+                       (tm, _gmm_tile(k), _gmm_tile(n)), None, None, False,
+                       interpret)
+    return out[:m]
+
+
+# ---------------------------------------------------------------------------
 # RWKV-6 (Finch) WKV recurrence
 # ---------------------------------------------------------------------------
 def wkv6(

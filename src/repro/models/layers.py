@@ -7,13 +7,14 @@ mesh context).
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ArchConfig, MoESpec
+from repro.configs.base import ArchConfig, MoESpec, YaRNSpec
 from repro.kernels import ops
 from repro.models.params import ParamDef
 from repro.parallel.axes import constrain
@@ -39,14 +40,45 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (out * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, D); positions: (S,) or broadcastable."""
+def yarn_frequencies(theta: float, dim: int, yarn: YaRNSpec) -> np.ndarray:
+    """The (dim/2,) rotary frequencies YaRN gives a head of ``dim``: each
+    original frequency theta^(-2i/dim) blended with its value divided by
+    ``factor``, by a linear ramp between the dimensions that turn
+    ``beta_fast`` and ``beta_slow`` times over ``original_max_position``."""
+    half = dim // 2
+    extrapolated = 1.0 / theta ** (np.arange(half, dtype=np.float64) * 2 / dim)
+    interpolated = extrapolated / yarn.factor
+
+    def dim_of(rotations: float) -> float:
+        return dim * math.log(yarn.original_max_position
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    return (interpolated * ramp + extrapolated * (1.0 - ramp)).astype(
+        np.float32)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         yarn: Optional[YaRNSpec] = None) -> jax.Array:
+    """x: (..., S, H, D); positions: (S,) or broadcastable.  With ``yarn``
+    the frequencies are YaRN's (computed once, at trace time) and cos and
+    sin are scaled by its attention factor."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if yarn is None:
+        freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    else:
+        freqs = jnp.asarray(yarn_frequencies(theta, d, yarn))
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (S, half)
     cos = jnp.cos(ang)[..., None, :]  # (S, 1, half)
     sin = jnp.sin(ang)[..., None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -70,16 +102,19 @@ def attention_defs(cfg: ArchConfig, cross: bool = False) -> dict:
 
 
 def _project_qkv(p: dict, xq: jax.Array, xkv: jax.Array, cfg: ArchConfig,
-                 positions: Optional[jax.Array], use_rope: bool):
+                 positions: Optional[jax.Array], kind: Optional[str]):
+    """q, k, v; rotated for a self-attention layer of ``kind`` (None: cross
+    attention, unrotated)."""
     q = jnp.einsum("bsd,dhk->bshk", xq, p["wq"].astype(xq.dtype))
     k = jnp.einsum("bsd,dhk->bshk", xkv, p["wk"].astype(xkv.dtype))
     v = jnp.einsum("bsd,dhk->bshk", xkv, p["wv"].astype(xkv.dtype))
     if "q_norm" in p:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    if use_rope and positions is not None:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+    if kind is not None and positions is not None:
+        yarn = cfg.yarn if kind == "global" else None
+        q = rope(q, positions, cfg.rope_theta, yarn)
+        k = rope(k, positions, cfg.rope_theta, yarn)
     q = constrain(q, "act_batch", "act_seq", "act_heads", None)
     k = constrain(k, "act_batch", "act_seq", "act_kv_heads", None)
     v = constrain(v, "act_batch", "act_seq", "act_kv_heads", None)
@@ -96,7 +131,7 @@ def self_attention(
     positions: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
     """Returns (attn output, (k, v)) — k/v reused for prefill cache writes."""
-    q, k, v = _project_qkv(p, x, x, cfg, positions, use_rope=True)
+    q, k, v = _project_qkv(p, x, x, cfg, positions, kind)
     window = cfg.window if kind == "local" else 0
     chunk = cfg.window if kind == "chunked" else 0
     o = ops.flash_attention(
@@ -125,7 +160,7 @@ def cross_attention(
     enc_out: jax.Array,  # (B, Se, d) encoder output
     cfg: ArchConfig,
 ) -> jax.Array:
-    q, k, v = _project_qkv(p, x, enc_out, cfg, None, use_rope=False)
+    q, k, v = _project_qkv(p, x, enc_out, cfg, None, None)
     o = ops.flash_attention(q, k, v, causal=False)
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
     return constrain(out, "act_batch", "act_seq", None)
@@ -144,7 +179,7 @@ def decode_self_attention(
     B, _, _ = x.shape
     L = k_cache.shape[1]
     positions = pos[None]  # (1,)
-    q, k, v = _project_qkv(p, x, x, cfg, positions, use_rope=True)
+    q, k, v = _project_qkv(p, x, x, cfg, positions, kind)
     slot = pos % L  # ring slot (== pos for a full-length global cache)
     k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0))
     v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0))
@@ -194,7 +229,8 @@ def ffn(p: dict, x: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Mixture of Experts (t5x-style dispatch/combine with per-group capacity)
+# Mixture of Experts: dropless (routes sorted by expert, grouped matmuls) or
+# t5x-style dispatch/combine with per-group capacity
 # ---------------------------------------------------------------------------
 def moe_defs(cfg: ArchConfig) -> dict:
     assert cfg.moe is not None
@@ -215,10 +251,41 @@ def _capacity(spec: MoESpec, group: int) -> int:
     return max(4, int(np.ceil(c / 4)) * 4)
 
 
-def moe_ffn(p: dict, x: jax.Array, cfg: ArchConfig) -> tuple[jax.Array, dict]:
-    """Routed expert FFN.  Returns (output, aux_losses)."""
+def _router(p: dict, x: jax.Array, top_k: int):
+    """float32 router logits and softmax, and each token's top-k experts
+    with their probabilities renormalised to sum to 1."""
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                        p["router"].astype(jnp.float32))
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, gate_idx = jax.lax.top_k(probs, top_k)
+    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    return logits, probs, gate_vals, gate_idx
+
+
+def _aux_losses(spec: MoESpec, logits, probs, routed_frac) -> dict:
+    """Switch-style load balance and router z-loss: the train loss's
+    terms (a prefill or decode step leaves them to dead-code removal)."""
+    E = spec.n_experts
+    lb = (probs.mean(axis=-2) * routed_frac).sum(-1).mean() * E \
+        * spec.load_balance_loss
+    z = jax.nn.logsumexp(logits, axis=-1)
+    return {"moe_lb_loss": lb, "moe_z_loss": (z**2).mean() * spec.router_z_loss}
+
+
+def moe_ffn(p: dict, x: jax.Array, cfg: ArchConfig, experts=None,
+            layer=None) -> tuple[jax.Array, dict]:
+    """Routed expert FFN.  Returns (output, aux): the aux losses, the share
+    of routes dropped, and ``routed``, the (E,) int32 count of the routes
+    each expert received.
+
+    A dropless layer may take its expert weights apart from ``p``:
+    ``experts`` holds every repeat's, stacked (R, E, ...), and ``layer``
+    is this one's repeat index."""
     spec = cfg.moe
     assert spec is not None
+    if spec.dropless:
+        with jax.named_scope("moe"):
+            return _moe_dropless(p, x, cfg, experts, layer)
     B, S, d = x.shape
     E, K = spec.n_experts, spec.top_k
     T = B * S
@@ -235,12 +302,7 @@ def moe_ffn(p: dict, x: jax.Array, cfg: ArchConfig) -> tuple[jax.Array, dict]:
     xg = x.reshape(n_groups, G, d)
     # groups inherit the token sharding: g = (batch x seq-chunks)
     xg = constrain(xg, "act_batch", None, None)
-    logits = jnp.einsum("gtd,de->gte", xg.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)  # (g, G, E)
-
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)  # (g, G, K)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    logits, probs, gate_vals, gate_idx = _router(p, xg, K)  # (g, G, K)
 
     # expert one-hot per routing slot: (g, G, K, E)
     onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)
@@ -274,16 +336,52 @@ def moe_ffn(p: dict, x: jax.Array, cfg: ArchConfig) -> tuple[jax.Array, dict]:
     if "shared" in p:
         out = out + ffn(p["shared"], x)
 
-    # aux losses (Switch-style load balance + router z-loss)
-    me = probs.mean(axis=1)  # (g, E) mean router prob
-    ce = onehot.sum(2).mean(axis=1)  # (g, E) fraction dispatched
-    lb_loss = (me * ce).sum(-1).mean() * E * spec.load_balance_loss
-    z = jax.nn.logsumexp(logits, axis=-1)
-    z_loss = (z**2).mean() * spec.router_z_loss
-    dropped = 1.0 - (keep.sum() / (n_groups * G * K))
-    aux = {
-        "moe_lb_loss": lb_loss,
-        "moe_z_loss": z_loss,
-        "moe_dropped_frac": dropped,
-    }
+    aux = _aux_losses(spec, logits, probs, onehot.sum(2).mean(axis=1))
+    aux["moe_dropped_frac"] = 1.0 - (keep.sum() / (n_groups * G * K))
+    aux["routed"] = onehot.sum((0, 1, 2)).astype(jnp.int32)
+    return out, aux
+
+
+def _moe_dropless(p: dict, x: jax.Array, cfg: ArchConfig, experts, layer):
+    """Every token through its top-k experts, none dropped: the T*K routes
+    sorted by expert, each expert's contiguous rows through its weights in
+    a grouped matmul (an expert that received no row is not read), then
+    unsorted and summed under the gate weights.
+
+    The expert weights are ``p``'s (E, ...), or ``experts``, every
+    repeat's stacked (R, E, ...): the grouped matmul then runs over all
+    R * E of them, with rows in the E groups of repeat ``layer`` only."""
+    spec = cfg.moe
+    B, S, d = x.shape
+    E, K = spec.n_experts, spec.top_k
+    T = B * S
+    dt = x.dtype
+    xt = x.reshape(T, d)
+    logits, probs, gate_vals, gate_idx = _router(p, xt, K)  # (T, K)
+    expert = gate_idx.reshape(T * K)
+    order = jnp.argsort(expert, stable=True)      # route slots by expert
+    sizes = jnp.bincount(expert, length=E).astype(jnp.int32)
+    rows = jnp.take(xt, order // K, axis=0)       # (T*K, d), sorted
+    groups = sizes
+    if experts is None:
+        w = [p[k].astype(dt) for k in ("w_gate", "w_up", "w_down")]
+    else:
+        w = [experts[k].astype(dt) for k in ("w_gate", "w_up", "w_down")]
+        R = w[0].shape[0]
+        w = [a.reshape(R * E, *a.shape[2:]) for a in w]
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((R * E,), jnp.int32), sizes, (layer * E,))
+    g = ops.grouped_matmul(rows, w[0], groups)
+    u = ops.grouped_matmul(rows, w[1], groups)
+    y = ops.grouped_matmul(jax.nn.silu(g) * u, w[2], groups)
+    # route slot r's output is sorted row where[r]
+    where = jnp.zeros((T * K,), jnp.int32).at[order].set(
+        jnp.arange(T * K, dtype=jnp.int32), unique_indices=True)
+    y = jnp.take(y, where, axis=0).reshape(T, K, d).astype(jnp.float32)
+    out = (y * gate_vals[..., None]).sum(1).astype(dt).reshape(B, S, d)
+    if "shared" in p:
+        out = out + ffn(p["shared"], x)
+    aux = _aux_losses(spec, logits, probs, sizes.astype(jnp.float32) / T)
+    aux["moe_dropped_frac"] = jnp.zeros((), jnp.float32)
+    aux["routed"] = sizes
     return out, aux

@@ -89,16 +89,17 @@ def make_eval_step(cfg: ArchConfig) -> Callable:
 def make_prefill_step(cfg: ArchConfig, cache_len: int = 0) -> Callable:
     """(params, batch) -> (next_token_logits, cache).
 
-    ``cache_len`` sizes the global-attention KV cache for the tokens that
-    decode will append (prompt + new tokens); 0 sizes it to the prompt,
-    and a decode step past it then overwrites the oldest slot."""
+    ``cache_len`` sizes the KV caches for the tokens that decode will
+    append (prompt + new tokens): global layers hold all of them, windowed
+    rings the window or all of them if fewer; 0 sizes every cache to the
+    prompt, and a decode step past it then overwrites the oldest slot."""
 
     def prefill_step(params, batch):
         h, _, caches = transformer.forward(params, cfg, batch, collect_cache=True)
         logits = transformer.unembed(params, cfg, h[:, -1:])
         seq_len = h.shape[1]
         if cache_len:
-            caches = transformer.extend_global_cache(cfg, caches, cache_len)
+            caches = transformer.extend_cache(cfg, caches, cache_len)
         cache = {"pos": jnp.asarray(seq_len, jnp.int32), "groups": caches}
         return logits, cache
 

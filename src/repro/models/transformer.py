@@ -107,7 +107,8 @@ def model_defs(cfg: ArchConfig) -> dict:
 # ---------------------------------------------------------------------------
 # Layer application (shared by train / prefill / decode)
 # ---------------------------------------------------------------------------
-def _apply_attn_layer(cfg, kind, p, h, *, causal, positions, enc_out):
+def _apply_attn_layer(cfg, kind, p, h, *, causal, positions, enc_out,
+                      experts=None, layer=None):
     a_out, kv = self_attention(
         p["attn"], rms_norm(h, p["ln1"], cfg.norm_eps), cfg, kind,
         causal=causal, positions=positions,
@@ -118,16 +119,16 @@ def _apply_attn_layer(cfg, kind, p, h, *, causal, positions, enc_out):
             p["xattn"], rms_norm(h, p["ln_x"], cfg.norm_eps), enc_out, cfg)
     hn = rms_norm(h, p["ln2"], cfg.norm_eps)
     if cfg.moe is not None:
-        f_out, aux = moe_ffn(p["moe"], hn, cfg)
-        aux_vec = _aux_vec(aux)
+        f_out, aux = moe_ffn(p["moe"], hn, cfg, experts=experts, layer=layer)
     else:
-        f_out = ffn(p["ffn"], hn)
-        aux_vec = _aux_zeros()
-    return h + f_out, aux_vec, kv
+        f_out, aux = ffn(p["ffn"], hn), None
+    return h + f_out, aux, kv
 
 
-def apply_layer(cfg, kind, p, h, *, causal=True, positions=None, enc_out=None):
-    """Full-sequence layer application. Returns (h, aux, prefill_cache)."""
+def apply_layer(cfg, kind, p, h, *, causal=True, positions=None, enc_out=None,
+                experts=None, layer=None):
+    """Full-sequence layer application. Returns (h, aux, prefill_cache).
+    ``experts`` and ``layer`` go to ``moe_ffn`` (see ``_layer_scan``)."""
     if kind == "rwkv":
         h, state = recurrent.rwkv_block(p, h, cfg)
         return h, _aux_zeros(), state
@@ -135,9 +136,19 @@ def apply_layer(cfg, kind, p, h, *, causal=True, positions=None, enc_out=None):
         h, state = recurrent.rglru_block(p, h, cfg)
         return h, _aux_zeros(), state
     h, aux, (k, v) = _apply_attn_layer(
-        cfg, kind, p, h, causal=causal, positions=positions, enc_out=enc_out)
-    L = cfg.kv_cache_len(kind, k.shape[1])
-    cache = {"k": k[:, -L:].astype(COMPUTE_DTYPE), "v": v[:, -L:].astype(COMPUTE_DTYPE)}
+        cfg, kind, p, h, causal=causal, positions=positions, enc_out=enc_out,
+        experts=experts, layer=layer)
+    S = k.shape[1]
+    L = cfg.kv_cache_len(kind, S)
+    k, v = k[:, -L:], v[:, -L:]
+    if S % L:  # a ring holds position p in slot p % L, where decode looks
+        k, v = jnp.roll(k, S % L, axis=1), jnp.roll(v, S % L, axis=1)
+    cache = {"k": k.astype(COMPUTE_DTYPE), "v": v.astype(COMPUTE_DTYPE)}
+    if aux is None:
+        aux_vec = _aux_zeros()
+    else:
+        aux_vec = _aux_vec(aux)
+        cache.update(routed=aux["routed"], touched=jnp.zeros((), jnp.int32))
     if enc_out is not None:
         # cache cross-attention K/V for decode
         xp = p["xattn"]
@@ -145,7 +156,7 @@ def apply_layer(cfg, kind, p, h, *, causal=True, positions=None, enc_out=None):
         xv = jnp.einsum("bsd,dhk->bshk", enc_out, xp["wv"].astype(enc_out.dtype))
         cache["xk"] = xk.astype(COMPUTE_DTYPE)
         cache["xv"] = xv.astype(COMPUTE_DTYPE)
-    return h, aux, cache
+    return h, aux_vec, cache
 
 
 def _decode_cross_attention(p, x, xk, xv, cfg):
@@ -164,8 +175,9 @@ def _decode_cross_attention(p, x, xk, xv, cfg):
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(dt))
 
 
-def decode_apply_layer(cfg, kind, p, h, cache, pos):
-    """One-token layer application. Returns (h, new_cache)."""
+def decode_apply_layer(cfg, kind, p, h, cache, pos, experts=None, layer=None):
+    """One-token layer application. Returns (h, new_cache).  ``experts``
+    and ``layer`` go to ``moe_ffn`` (see ``_layer_scan``)."""
     if kind == "rwkv":
         h, state = recurrent.rwkv_block(p, h, cfg, state=cache)
         return h, state
@@ -185,7 +197,11 @@ def decode_apply_layer(cfg, kind, p, h, cache, pos):
             cache["xk"], cache["xv"], cfg)
     hn = rms_norm(h, p["ln2"], cfg.norm_eps)
     if cfg.moe is not None:
-        f_out, _ = moe_ffn(p["moe"], hn, cfg)
+        f_out, aux = moe_ffn(p["moe"], hn, cfg, experts=experts, layer=layer)
+        # routing counters: routes per expert, and distinct experts a step
+        new_cache["routed"] = cache["routed"] + aux["routed"]
+        new_cache["touched"] = cache["touched"] + jnp.sum(
+            aux["routed"] > 0, dtype=jnp.int32)
     else:
         f_out = ffn(p["ffn"], hn)
     return h + f_out, new_cache
@@ -208,21 +224,51 @@ def _remat(fn, cfg: ArchConfig):
     return jax.checkpoint(fn)  # "full": save nothing
 
 
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def _layer_scan(cfg: ArchConfig, repeats: int, gparams):
+    """What a group's layer scan runs over, and ``fetch(xs, i)``, which
+    gives the body the params of the pattern's layer i and the keyword
+    arguments its layer application takes beside them.
+
+    A dropless MoE layer's expert weights stay out of the scan, whole:
+    its grouped matmul takes them as a kernel operand, and a per-layer
+    slice of the stacked weights would make XLA copy all of a layer's
+    experts at every step (it cannot fuse a slice into the kernel).  The
+    body hands them on stacked, as ``experts``, with the repeat's index
+    as ``layer``."""
+    if cfg.moe is None or not cfg.moe.dropless:
+        return gparams, lambda xs, i: (xs[f"p{i}"], {})
+    held = {k: {w: p["moe"][w] for w in EXPERT_WEIGHTS}
+            for k, p in gparams.items()}
+    rest = {k: dict(p, moe={w: v for w, v in p["moe"].items()
+                            if w not in EXPERT_WEIGHTS})
+            for k, p in gparams.items()}
+
+    def fetch(xs, i):
+        return xs[0][f"p{i}"], {"experts": held[f"p{i}"], "layer": xs[1]}
+
+    return (rest, jnp.arange(repeats)), fetch
+
+
 def run_groups(params_groups, cfg: ArchConfig, h, *, causal=True,
                positions=None, enc_out=None, collect_cache=False):
     """Apply all block groups. Returns (h, aux_total, caches|None)."""
     aux = _aux_zeros()
     caches = []
     for (pattern, repeats), gparams in zip(cfg.block_groups, params_groups):
+        gparams, fetch = _layer_scan(cfg, repeats, gparams)
         if collect_cache:
             def body(carry, xs):
                 hh, av = carry
                 hh = constrain(hh, "act_batch", "act_res_seq", None)
                 cache_out = {}
                 for i, kind in enumerate(pattern):
+                    p, held = fetch(xs, i)
                     hh, a, c = apply_layer(
-                        cfg, kind, xs[f"p{i}"], hh, causal=causal,
-                        positions=positions, enc_out=enc_out)
+                        cfg, kind, p, hh, causal=causal,
+                        positions=positions, enc_out=enc_out, **held)
                     av = av + a
                     cache_out[f"p{i}"] = c
                 return (hh, av), cache_out
@@ -234,9 +280,10 @@ def run_groups(params_groups, cfg: ArchConfig, h, *, causal=True,
                 hh, av = carry
                 hh = constrain(hh, "act_batch", "act_res_seq", None)
                 for i, kind in enumerate(pattern):
+                    p, held = fetch(xs, i)
                     hh, a, _ = apply_layer(
-                        cfg, kind, xs[f"p{i}"], hh, causal=causal,
-                        positions=positions, enc_out=enc_out)
+                        cfg, kind, p, hh, causal=causal,
+                        positions=positions, enc_out=enc_out, **held)
                     av = av + a
                 return (hh, av), None
 
@@ -248,12 +295,15 @@ def run_groups_decode(params_groups, cfg: ArchConfig, h, cache_groups, pos):
     new_caches = []
     for (pattern, repeats), gparams, gcache in zip(
             cfg.block_groups, params_groups, cache_groups):
+        gparams, fetch = _layer_scan(cfg, repeats, gparams)
+
         def body(hh, xs):
             p_slice, c_slice = xs
             new_c = {}
             for i, kind in enumerate(pattern):
+                p, held = fetch(p_slice, i)
                 hh, nc = decode_apply_layer(
-                    cfg, kind, p_slice[f"p{i}"], hh, c_slice[f"p{i}"], pos)
+                    cfg, kind, p, hh, c_slice[f"p{i}"], pos, **held)
                 new_c[f"p{i}"] = nc
             return hh, new_c
 
@@ -430,28 +480,34 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int, enc_len: int = 0):
                     se = enc_len or seq_len
                     ent["xk"] = jnp.zeros((batch, se, cfg.n_kv_heads, cfg.d_head), COMPUTE_DTYPE)
                     ent["xv"] = jnp.zeros((batch, se, cfg.n_kv_heads, cfg.d_head), COMPUTE_DTYPE)
+                if cfg.moe is not None:
+                    ent["routed"] = jnp.zeros((cfg.moe.n_experts,), jnp.int32)
+                    ent["touched"] = jnp.zeros((), jnp.int32)
             g[f"p{i}"] = jax.tree_util.tree_map(
                 lambda x, r=repeats: jnp.zeros((r,) + x.shape, x.dtype), ent)
         groups.append(g)
     return {"pos": jnp.zeros((), jnp.int32), "groups": groups}
 
 
-def extend_global_cache(cfg: ArchConfig, caches: list, length: int) -> list:
-    """Pad every global-attention layer's prefill K/V (stacked
-    (layers, B, S, KV, D)) to ``length`` slots; the empty slots are masked
-    by decode until it writes them.  Windowed and recurrent caches keep
-    their size."""
+def extend_cache(cfg: ArchConfig, caches: list, length: int) -> list:
+    """Size prefill's K/V caches (stacked (layers, B, S, KV, D)) for
+    ``length`` positions: every global-attention layer's to ``length``
+    slots, every windowed ring to the smaller of the window and
+    ``length``.  The empty slots are masked by decode until it writes them
+    (a ring's prefill positions already sit in their slots p % L).
+    Recurrent states keep their size."""
     out = []
     for (pattern, _), g in zip(cfg.block_groups, caches):
         g = dict(g)
         for i, kind in enumerate(pattern):
-            if kind != "global":
+            if kind not in ATTN_KINDS:
                 continue
             ent = dict(g[f"p{i}"])
+            want = cfg.kv_cache_len(kind, length)
             for name in ("k", "v"):
                 x = ent[name]
                 ent[name] = jnp.pad(
-                    x, ((0, 0), (0, 0), (0, length - x.shape[2]), (0, 0),
+                    x, ((0, 0), (0, 0), (0, want - x.shape[2]), (0, 0),
                         (0, 0)))
             g[f"p{i}"] = ent
         out.append(g)
@@ -474,6 +530,9 @@ def cache_axes(cfg: ArchConfig):
                 if cfg.enc_dec:
                     ent["xk"] = kv
                     ent["xv"] = kv
+                if cfg.moe is not None:
+                    ent["routed"] = ("layers", None)
+                    ent["touched"] = ("layers",)
             g[f"p{i}"] = ent
         groups.append(g)
     return {"pos": (), "groups": groups}

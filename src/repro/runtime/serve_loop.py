@@ -37,6 +37,8 @@ class ServeReport:
     tokens_generated: int
     wall_s: float
     outputs: np.ndarray
+    # an MoE model's routing counters (see ``Server.routes``), else None
+    routes: Optional[dict] = None
 
 
 class Server:
@@ -74,6 +76,7 @@ class Server:
                             (sc.batch, sc.prompt_len, self.cfg.d_model),
                             jnp.bfloat16)
                     logits, cache = self.prefill(self.params, batch)
+                    counted = cache
                     out = np.zeros((sc.batch, sc.max_new_tokens), np.int32)
                     tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
                     for i in range(sc.max_new_tokens):
@@ -84,6 +87,7 @@ class Server:
                         # the step that consumes token i is queued before
                         # the host waits for token i, so the device runs
                         # from one step straight into the next
+                        counted = cache
                         with span("repro.serve.dispatch", batch=b, token=i,
                                   ahead=not tok.is_ready()):
                             logits, cache = self.decode(
@@ -97,7 +101,37 @@ class Server:
                     retries += 1
                     if retries > 8:
                         raise
+            routes = self.routes(b, counted, sc.max_new_tokens - 1)
         return ServeReport(
             completed_requests=sc.batch, retries=retries,
             tokens_generated=int(sc.batch * sc.max_new_tokens),
-            wall_s=whole.seconds, outputs=out)
+            wall_s=whole.seconds, outputs=out, routes=routes)
+
+    def routes(self, b: int, cache: dict, steps: int) -> Optional[dict]:
+        """An MoE model's routing counters in ``cache``, copied to the host
+        once and recorded as a ``repro.serve.moe`` span; None for a model
+        without experts.  ``cache`` is the last decode step's input, whose
+        counters hold the prefill and the ``steps`` decode steps before
+        it: they were ready when the last token was, so the copy waits on
+        nothing the loop did not (the batch's last, unused step runs on).
+
+        ``routed`` (MoE layers, experts): routes each expert received;
+        ``touched`` (MoE layers,): distinct experts each decode step read,
+        summed over the steps."""
+        ents = [c for g in cache["groups"] for c in g.values()
+                if "routed" in c]
+        if not ents:
+            return None
+        routed, touched = jax.device_get(
+            ([c["routed"] for c in ents], [c["touched"] for c in ents]))
+        routed = np.concatenate(routed).astype(np.int64)  # (layers, E)
+        touched = np.concatenate(touched).astype(np.int64)
+        stats = {
+            "experts_touched": float(touched.sum() / (touched.size * steps))
+            if steps else 0.0,
+            "max_load_over_mean": float(
+                (routed.max(axis=1) / routed.mean(axis=1)).max()),
+            "decode_steps": steps,
+        }
+        with span("repro.serve.moe", batch=b, **stats):
+            return dict(stats, routed=routed, touched=touched)

@@ -27,6 +27,9 @@ ASSIGNED = {
                           n_kv_heads=8, d_ff=16384, vocab_size=32768),
     "llava-next-34b": dict(n_layers=60, d_model=7168, n_heads=56,
                            n_kv_heads=8, d_ff=20480, vocab_size=64000),
+    "mellum2-12b": dict(n_layers=28, d_model=2304, n_heads=32, n_kv_heads=4,
+                        d_head=128, d_ff=896, vocab_size=98304, window=1024,
+                        norm_eps=1e-6, rope_theta=500_000.0),
 }
 
 
@@ -53,6 +56,11 @@ def test_moe_specs():
     assert mix.moe.n_experts == 8 and mix.moe.top_k == 2
     l4 = get_arch("llama4-scout-17b-a16e")
     assert l4.moe.n_experts == 16 and l4.moe.top_k == 1 and l4.moe.shared_expert
+    mel = get_arch("mellum2-12b")
+    assert mel.moe.n_experts == 64 and mel.moe.top_k == 8
+    assert mel.moe.dropless and not mel.moe.shared_expert
+    assert mel.layer_kinds() == ["local", "local", "local", "global"] * 7
+    assert mel.yarn.factor == 16 and mel.yarn.original_max_position == 8192
 
 
 def test_param_counts_plausible():
@@ -63,6 +71,7 @@ def test_param_counts_plausible():
         "rwkv6-7b": (7.6e9, 0.35),
         "mixtral-8x22b": (141e9, 0.2),
         "llava-next-34b": (34e9, 0.25),
+        "mellum2-12b": (12e9, 0.05),
     }
     for name, (target, tol) in approx.items():
         n = get_arch(name).param_count()
@@ -73,6 +82,8 @@ def test_moe_active_params_less_than_total():
     for name in ("mixtral-8x22b", "llama4-scout-17b-a16e"):
         cfg = get_arch(name)
         assert cfg.active_param_count() < 0.55 * cfg.param_count()
+    # Mellum2-12B-A2.5B: 2.5 B of its 12 B active a token
+    assert abs(get_arch("mellum2-12b").active_param_count() - 2.5e9) < 0.1e9
 
 
 def test_long_context_flags():

@@ -1,6 +1,6 @@
 """Compile rehearsals for a TPU v5e chip, at real widths, with no chip.
 
-The three Pallas kernels are compiled for a *described* v5e chip: the TPU
+The Pallas kernels are compiled for a *described* v5e chip: the TPU
 compiler refuses here what interpret mode accepts (blocks off the (8, 128)
 tiling, unpacked dynamic slices, too much VMEM).  Nothing runs, so these
 say nothing about results or times.
@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import get_arch
+from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rglru_scan import rglru
 from repro.kernels.rwkv6_scan import wkv6
@@ -99,3 +100,43 @@ def test_rglru_compiles_for_v5e(one_chip):
     _compile(lambda x, la, h0: rglru(x, la, h0), one_chip,
              ((2, 2048, W), jnp.bfloat16), ((2, 2048, W), jnp.float32),
              ((2, W), jnp.float32))
+
+
+# mellum2-12b's experts (d 2304, f 896, 64 of them): a decode step's 64
+# routes (8 tokens x top 8) through gate/up and down, and a prefill's
+# 8 x 4096 x 8
+@pytest.mark.parametrize("rows,k,n", [(64, 2304, 896), (64, 896, 2304),
+                                      (262144, 2304, 896)])
+def test_gmm_compiles_for_v5e(one_chip, rows, k, n):
+    _compile(ops.pallas_gmm, one_chip, ((rows, k), jnp.bfloat16),
+             ((64, k, n), jnp.bfloat16), ((64,), jnp.int32))
+
+
+def test_mellum_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The decode step of the chip cell's cut (8 layers, two periods of
+    three sliding layers and a full one) at batch 8 against a 4224-slot
+    global cache, with the grouped matmul on its Pallas path."""
+    from repro.models import params as pmod
+    from repro.models import transformer
+    from repro.models.steps import make_decode_step
+
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    base = get_arch("mellum2-12b")
+    cfg = base.replace(n_layers=8,
+                       block_groups=((base.block_groups[0][0], 2),))
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shapes(pmod.abstract(pmod.cast_defs(
+        transformer.model_defs(cfg), jnp.bfloat16)))
+    cache = shapes(jax.eval_shape(
+        lambda: transformer.init_cache(cfg, 8, 4096 + 128)))
+    tok = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(make_decode_step(cfg)).lower(params, cache,
+                                                    tok).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        >= 3   # gate, up and down of one pattern position at least
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
