@@ -10,6 +10,17 @@ TPU-native design (DESIGN.md §3: adapt, don't port):
     ``pl.when`` — fully-masked (q_block, kv_block) pairs issue no MXU work,
     which the blockwise-jnp dry-run path cannot do (its rectangular scan
     carries ~2x causal overcompute; see EXPERIMENTS.md §Perf);
+  * only the tiles that the mask's edge crosses (the causal diagonal, the
+    window's far edge, a chunk edge) build the mask: a *full* tile, every
+    pair of which attends, runs no iota, compare or ``where``
+    (``block_class`` sorts the tiles);
+  * the MXU takes q, k, v and the probabilities in the inputs' own dtype
+    and accumulates in f32; the softmax state (m, l, acc), ``exp`` and the
+    scale stay f32;
+  * the row state m and l is kept as (block_q, 128), the same value in
+    every lane, so that widening it over a tile's columns or over acc
+    repeats whole vregs instead of broadcasting a (block_q, 1) column
+    across lanes in every tile;
   * GQA is expressed in the index maps: kv head = q head // group size, so
     no KV replication is materialized.
 
@@ -38,6 +49,43 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.ops import _flash_bwd_impl
 
 NEG_INF = -1e30
+LANES = 128   # m and l are kept as (block_q, LANES), every lane alike
+
+
+def block_class(q_start, k_start, block_q: int, block_k: int, causal: bool,
+                window: int, chunk: int):
+    """Sort the (q block, kv block) tile at (q_start, k_start) by the mask.
+
+    Returns ``(run, full)``: ``run`` is false where no (q, k) pair of the
+    tile attends (*skipped*), ``full`` true where every pair does (*full*);
+    a tile with ``run`` and not ``full`` is *masked*, as the mask's edge
+    crosses it.  ``full`` is exact; ``run`` may keep a tile that a chunk
+    edge and the causal or window edge mask together.  Takes Python ints
+    (giving bools) or traced scalars alike.
+    """
+    lo = q_start - (k_start + block_k - 1)   # least q - k in the tile
+    hi = q_start + block_q - 1 - k_start      # greatest q - k
+    run = full = True
+    if causal:
+        run &= hi >= 0
+        full &= lo >= 0
+    if window > 0:
+        run &= lo < window
+        full &= hi < window
+    if chunk > 0:
+        q0, q1 = q_start // chunk, (q_start + block_q - 1) // chunk
+        k0, k1 = k_start // chunk, (k_start + block_k - 1) // chunk
+        run &= (q1 >= k0) & (q0 <= k1)
+        full &= (q0 == q1) & (k0 == k1) & (q0 == k0)
+    return run, full
+
+
+def _widen(x, n: int):
+    """A lane-replicated (rows, LANES) state as (rows, n): whole vregs
+    repeated where n is a multiple of LANES, with no lane broadcast."""
+    if n % LANES == 0:
+        return pltpu.repeat(x, n // LANES, 1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
@@ -54,54 +102,54 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
 
     q_start = qi * block_q
     k_start = kj * block_k
+    run, full = block_class(q_start, k_start, block_q, block_k, causal,
+                            window, chunk)
 
-    # block-level reachability: can any (q, k) pair in this tile attend?
-    run = True
-    if causal:
-        run = jnp.logical_and(run, q_start + block_q - 1 >= k_start)
-    if window > 0:
-        run = jnp.logical_and(run, q_start < k_start + block_k + window)
-    if chunk > 0:
-        run = jnp.logical_and(
-            run, (q_start + block_q - 1) // chunk >= k_start // chunk)
-        run = jnp.logical_and(run, q_start // chunk <= (k_start + block_k - 1) // chunk)
-
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (bq, d)
-        k = k_ref[0, 0].astype(jnp.float32)  # (bk, d)
-        v = v_ref[0, 0].astype(jnp.float32)
+    def _update(masked: bool):
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if softcap > 0:
             s = jnp.tanh(s / softcap) * softcap
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        mask = jnp.ones((block_q, block_k), jnp.bool_)
-        if causal:
-            mask &= q_pos >= k_pos
-        if window > 0:
-            mask &= (q_pos - k_pos) < window
-        if chunk > 0:
-            mask &= (q_pos // chunk) == (k_pos // chunk)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]  # (bq, 1)
+        if masked:
+            q_pos = q_start + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            k_pos = k_start + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            mask = jnp.ones((block_q, block_k), jnp.bool_)
+            if causal:
+                mask &= q_pos >= k_pos
+            if window > 0:
+                mask &= (q_pos - k_pos) < window
+            if chunk > 0:
+                mask &= (q_pos // chunk) == (k_pos // chunk)
+            s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_scr[...]  # (bq, LANES), every lane alike
         l_prev = l_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - _widen(m_new, block_k))
+        if masked:
+            p = jnp.where(mask, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_scr[...] = l_prev * corr + p.sum(axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * _widen(corr, acc_scr.shape[1]) + pv
         m_scr[...] = m_new
+
+    pl.when(full)(functools.partial(_update, masked=False))
+    if not isinstance(full, bool):   # some tiles are crossed by the mask
+        pl.when(jnp.logical_and(run, jnp.logical_not(full)))(
+            functools.partial(_update, masked=True))
 
     @pl.when(kj == n_kv - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-20)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[...] + jnp.log(l)
+        o_ref[0, 0] = (acc_scr[...] / _widen(l, acc_scr.shape[1])).astype(
+            o_ref.dtype)
+        lse_ref[0, 0] = (m_scr[...] + jnp.log(l))[:, :1]
 
 
 def flash_attention(
@@ -201,18 +249,11 @@ def _forward(q, k, v, causal, window, chunk, softcap, block_q, block_k,
             jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse
 
-
-def vmem_bytes(block_q: int, block_k: int, d: int, dtype_bytes: int = 2) -> int:
-    """Working-set estimate for BlockSpec sizing: q,k,v tiles + f32 scratch."""
-    tiles = (block_q * d + 2 * block_k * d) * dtype_bytes
-    scratch = (2 * block_q + block_q * d) * 4
-    out = block_q * d * dtype_bytes
-    return tiles + scratch + out

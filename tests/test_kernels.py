@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.flash_attention import block_class
 from repro.kernels.flash_attention import flash_attention as flash_pallas
 from repro.kernels.rglru_scan import rglru as rglru_pallas
 from repro.kernels.rwkv6_scan import wkv6 as wkv6_pallas
@@ -25,55 +26,93 @@ def _qkv(B, S, H, KV, D, dtype):
 
 
 SWEEP = [
-    # (B, S, H, KV, D, causal, window, chunk)
-    (2, 256, 4, 2, 64, True, 0, 0),
-    (1, 512, 4, 4, 64, False, 0, 0),
-    (1, 512, 8, 1, 64, True, 0, 0),      # MQA
-    (1, 1024, 4, 2, 64, True, 256, 0),   # sliding window
-    (1, 1024, 2, 2, 64, True, 0, 256),   # chunked
-    (2, 256, 4, 4, 128, True, 0, 0),     # d_head 128
+    # (B, S, H, KV, D, causal, window, chunk, softcap)
+    (2, 256, 4, 2, 64, True, 0, 0, 0.0),
+    (1, 512, 4, 4, 64, False, 0, 0, 0.0),
+    (1, 512, 8, 1, 64, True, 0, 0, 0.0),      # MQA
+    (1, 1024, 4, 2, 64, True, 256, 0, 0.0),   # sliding window
+    (1, 1024, 2, 2, 64, True, 0, 256, 0.0),   # chunked
+    (2, 256, 4, 4, 128, True, 0, 0, 0.0),     # d_head 128
+    # at block 128, a window and chunks that are not multiples of the
+    # block: their edges cross tiles off the diagonal
+    (1, 1024, 4, 2, 64, True, 200, 0, 0.0),
+    (1, 1024, 2, 2, 64, True, 0, 192, 0.0),
+    (1, 768, 2, 1, 64, False, 200, 192, 0.0),  # full, masked, skipped
+    (1, 64, 4, 2, 128, True, 0, 0, 0.0),      # one block, under 128 wide
+    (1, 512, 4, 2, 64, True, 0, 0, 2.0),      # softcap
 ]
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("case", SWEEP)
 def test_pallas_flash_matches_oracle(case, dtype):
-    B, S, H, KV, D, causal, window, chunk = case
+    B, S, H, KV, D, causal, window, chunk, softcap = case
     q, k, v = _qkv(B, S, H, KV, D, dtype)
     got = flash_pallas(q, k, v, causal=causal, window=window, chunk=chunk,
-                       block_q=128, block_k=128, interpret=True)
+                       softcap=softcap, block_q=128, block_k=128,
+                       interpret=True)
     want = ref.attention_ref(q, k, v, causal=causal, window=window,
-                             chunk=chunk)
+                             chunk=chunk, softcap=softcap)
     tol = 2e-6 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=tol)
 
 
+@pytest.mark.parametrize("causal,window,chunk", [
+    (True, 0, 0), (False, 0, 0),
+    (True, 256, 0), (True, 200, 0), (True, 100, 0), (False, 200, 0),
+    (True, 0, 256), (True, 0, 192), (True, 0, 100), (False, 0, 192),
+    (True, 200, 192), (False, 200, 192),
+])
+def test_flash_block_class_matches_element_mask(causal, window, chunk):
+    """The kernel's tile sorting against a brute-force element mask: a
+    *full* tile has every pair visible, a *skipped* one none, and every
+    other tile takes the masked body."""
+    S, blk = 1024, 128
+    pos = jnp.arange(S)
+    mask = np.asarray(ref._mask(pos, pos, causal=causal, window=window,
+                                chunk=chunk))
+    seen = set()
+    for qs in range(0, S, blk):
+        for ks in range(0, S, blk):
+            tile = mask[qs:qs + blk, ks:ks + blk]
+            run, full = block_class(qs, ks, blk, blk, causal, window, chunk)
+            assert full == tile.all(), (qs, ks)
+            if not run:
+                assert not tile.any(), (qs, ks)
+            seen.add("full" if full else "masked" if run else "skipped")
+    if causal:   # the diagonal is crossed, the far past is never seen
+        assert {"masked", "skipped"} <= seen
+    if not (causal or window or chunk):
+        assert seen == {"full"}
+
+
 @pytest.mark.parametrize("case", SWEEP[:4])
 def test_jnp_flash_matches_oracle(case):
-    B, S, H, KV, D, causal, window, chunk = case
+    B, S, H, KV, D, causal, window, chunk, softcap = case
     q, k, v = _qkv(B, S, H, KV, D, jnp.float32)
-    got = ops._flash(q, k, v, causal, window, chunk, 0.0, 0, 128, 128)
+    got = ops._flash(q, k, v, causal, window, chunk, softcap, 0, 128, 128)
     want = ref.attention_ref(q, k, v, causal=causal, window=window,
-                             chunk=chunk)
+                             chunk=chunk, softcap=softcap)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
 
 
-@pytest.mark.parametrize("case", [SWEEP[0], SWEEP[2], SWEEP[3], SWEEP[4]])
+@pytest.mark.parametrize("case", [SWEEP[0], SWEEP[2], SWEEP[3], SWEEP[4],
+                                  *SWEEP[6:]])
 def test_pallas_flash_vjp_matches_oracle_grads(case):
     """Pallas forward (+ lse) with the blockwise-jnp backward."""
-    B, S, H, KV, D, causal, window, chunk = case
+    B, S, H, KV, D, causal, window, chunk, softcap = case
     q, k, v = _qkv(B, S, H, KV, D, jnp.float32)
     do = jax.random.normal(KEY, (B, S, H, D), jnp.float32)
 
     def f_pl(q, k, v):
         return (flash_pallas(q, k, v, causal=causal, window=window,
-                             chunk=chunk, block_q=128, block_k=128,
-                             interpret=True) * do).sum()
+                             chunk=chunk, softcap=softcap, block_q=128,
+                             block_k=128, interpret=True) * do).sum()
 
     def f_ref(q, k, v):
         return (ref.attention_ref(q, k, v, causal=causal, window=window,
-                                  chunk=chunk) * do).sum()
+                                  chunk=chunk, softcap=softcap) * do).sum()
 
     g1 = jax.grad(f_pl, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
@@ -119,17 +158,17 @@ def test_pallas_flash_sharded_under_mesh_subprocess():
 
 @pytest.mark.parametrize("case", [SWEEP[0], SWEEP[3], SWEEP[4]])
 def test_flash_custom_vjp_matches_oracle_grads(case):
-    B, S, H, KV, D, causal, window, chunk = case
+    B, S, H, KV, D, causal, window, chunk, softcap = case
     q, k, v = _qkv(B, S, H, KV, D, jnp.float32)
     do = jax.random.normal(KEY, (B, S, H, D), jnp.float32)
 
     def f_fl(q, k, v):
-        return (ops._flash(q, k, v, causal, window, chunk, 0.0, 0,
+        return (ops._flash(q, k, v, causal, window, chunk, softcap, 0,
                            128, 128) * do).sum()
 
     def f_ref(q, k, v):
         return (ref.attention_ref(q, k, v, causal=causal, window=window,
-                                  chunk=chunk) * do).sum()
+                                  chunk=chunk, softcap=softcap) * do).sum()
 
     g1 = jax.grad(f_fl, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)

@@ -73,6 +73,22 @@ def test_flash_forward_compiles_for_v5e(one_chip, batch, seq):
              one_chip, *_starcoder2_qkv(batch, seq))
 
 
+# the serve cells' prefill shapes: granite-20b's causal MQA (48 heads over
+# one kv head) at 1 x 8192, and mellum2-12b's sliding layers (GQA 32/4,
+# window 1024) at 8 x 4096
+@pytest.mark.parametrize("arch,batch,seq,window",
+                         [("granite-20b", 1, 8192, 0),
+                          ("mellum2-12b", 8, 4096, 1024)])
+def test_flash_forward_compiles_for_v5e_at_serve_shapes(one_chip, arch, batch,
+                                                        seq, window):
+    c = get_arch(arch)
+    q = ((batch, seq, c.n_heads, c.d_head), jnp.bfloat16)
+    kv = ((batch, seq, c.n_kv_heads, c.d_head), jnp.bfloat16)
+    _compile(lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                             window=window),
+             one_chip, q, kv, kv)
+
+
 def test_flash_vjp_compiles_for_v5e(one_chip):
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True).astype(
