@@ -10,7 +10,7 @@ Design notes
   pattern is a tuple of layer kinds (e.g. ``("local",)*5 + ("global",)`` for
   gemma3's 5:1 local:global interleave).  Each group is executed with one
   ``jax.lax.scan`` over ``repeats`` so the lowered HLO is O(#groups), not
-  O(#layers) — this keeps 52-layer 512-device dry-run compiles fast.
+  O(#layers) — this keeps the compiles of deep models fast.
 * Remainder layers (when ``n_layers`` is not a multiple of the pattern
   length) become their own group, so the exact published layer count is
   preserved.
@@ -38,8 +38,10 @@ class MoESpec:
     capacity_factor: Optional[float] = 1.25
     # Dense FFN run in parallel with the routed experts (llama4-style).
     shared_expert: bool = False
-    # Tokens are routed within groups of this size; dispatch/combine einsum
-    # FLOPs scale with group_size (see DESIGN.md §4).
+    # Tokens are routed within groups of this size; the capacity path's
+    # one-hot dispatch/combine tensors are (group, experts, slots) with
+    # slots in proportion to the group, so their einsum FLOPs a token grow
+    # with group_size.
     group_size: int = 1024
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
@@ -84,28 +86,6 @@ class RWKVSpec:
 
 
 @dataclass(frozen=True)
-class ShapeSpec:
-    """One assigned (input-shape) cell."""
-
-    name: str
-    kind: str  # "train" | "prefill" | "decode"
-    seq_len: int
-    global_batch: int
-
-    @property
-    def tokens(self) -> int:
-        return self.seq_len * self.global_batch
-
-
-SHAPES: dict[str, ShapeSpec] = {
-    "train_4k": ShapeSpec("train_4k", "train", 4_096, 256),
-    "prefill_32k": ShapeSpec("prefill_32k", "prefill", 32_768, 32),
-    "decode_32k": ShapeSpec("decode_32k", "decode", 32_768, 128),
-    "long_500k": ShapeSpec("long_500k", "decode", 524_288, 1),
-}
-
-
-@dataclass(frozen=True)
 class ArchConfig:
     name: str
     family: str  # dense | moe | ssm | hybrid | audio | vlm
@@ -145,8 +125,6 @@ class ArchConfig:
     tie_embeddings: bool = False
     ffn_gated: bool = True  # SwiGLU (3 matmuls) vs classic MLP (2 matmuls)
     norm_eps: float = 1e-5
-    # Whether a 524k decode is servable sub-quadratically (SSM / windowed).
-    long_context_ok: bool = False
 
     # Training hyper-knobs (overridable per run).
     remat_policy: str = "full"  # none | dots | full
@@ -184,7 +162,7 @@ class ArchConfig:
     def count_kind(self, *kinds: str) -> int:
         return sum(1 for k in self.layer_kinds() if k in kinds)
 
-    # -- parameter accounting (used by roofline + checkpoint sizing) --------
+    # -- parameter accounting ----------------------------------------------
     def attn_params(self) -> int:
         d = self.d_model
         return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d

@@ -29,7 +29,6 @@ register(
         qk_norm=True,
         rope_theta=1_000_000.0,
         tie_embeddings=True,
-        long_context_ok=True,
         notes="5:1 local:global; 262k vocab stresses embedding sharding + CE loss",
         source="hf:google/gemma-3-4b-pt",
     )

@@ -15,7 +15,6 @@ register(
         block_groups=((("global",), 52),),
         ffn_gated=False,
         rope_theta=10_000.0,
-        long_context_ok=False,  # pure full attention: long_500k skipped
         notes="llama-arch code model; MQA makes KV tiny but un-shardable by head",
         source="arXiv:2405.04324",
     )

@@ -27,7 +27,6 @@ register(
             group_size=1024,
         ),
         rope_theta=500_000.0,
-        long_context_ok=True,
         notes="top-1 routed + always-on shared expert; early-fusion frontend stubbed",
         source="hf:meta-llama/Llama-4-Scout-17B-16E",
     )

@@ -23,7 +23,6 @@ register(
         block_groups=((("global",), 60),),
         n_patches=576,
         rope_theta=5_000_000.0,
-        long_context_ok=False,  # pure full attention: long_500k skipped
         notes="patch embeddings occupy the first 576 positions of the sequence",
         source="hf:llava-hf/llava-v1.6-34b-hf",
     )

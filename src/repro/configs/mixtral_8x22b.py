@@ -22,7 +22,6 @@ register(
             group_size=1024,
         ),
         rope_theta=1_000_000.0,
-        long_context_ok=True,  # SWA bounds decode KV at the window
         notes="largest assigned model (~140B total params); checkpoint-size stress",
         source="arXiv:2401.04088",
     )

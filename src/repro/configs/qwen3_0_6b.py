@@ -16,7 +16,6 @@ register(
         qk_norm=True,
         rope_theta=1_000_000.0,
         tie_embeddings=True,
-        long_context_ok=False,  # pure full attention: long_500k skipped
         notes="qk_norm per-head RMSNorm; vocab-dominated parameter budget",
         source="hf:Qwen/Qwen3-0.6B",
     )

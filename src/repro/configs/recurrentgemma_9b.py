@@ -26,7 +26,6 @@ register(
         rglru=RGLRUSpec(lru_width=4096, conv_width=4, n_heads=16),
         rope_theta=10_000.0,
         tie_embeddings=True,
-        long_context_ok=True,
         notes="RG-LRU linear recurrence; attention bounded at window 2048",
         source="arXiv:2402.19427",
     )

@@ -19,7 +19,6 @@ register(
         vocab_size=32000,
         block_groups=((("global",), 32),),
         rope_theta=10_000.0,
-        long_context_ok=False,
         notes="paper-representative LLaMa-class pretraining job",
         source="arXiv:2302.13971",
     )

@@ -14,7 +14,6 @@ register(
         vocab_size=65536,
         block_groups=((("rwkv",), 32),),
         rwkv=RWKVSpec(head_dim=64, ddlerp_rank=32, decay_rank=64),
-        long_context_ok=True,
         notes="O(1) decode state: (heads, 64, 64) WKV matrix per layer",
         source="arXiv:2404.05892",
     )

@@ -24,7 +24,6 @@ register(
         n_enc_layers=24,
         enc_len_ratio=1.0,
         rope_theta=10_000.0,
-        long_context_ok=False,  # full attention enc-dec: long_500k skipped
         notes="enc-dec; decode shapes lower the decoder serve_step w/ cross-attn",
         source="arXiv:2308.11596",
     )

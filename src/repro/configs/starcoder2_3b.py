@@ -15,7 +15,6 @@ register(
         block_groups=((("global",), 30),),
         ffn_gated=False,
         rope_theta=999_999.4,
-        long_context_ok=False,  # pure full attention: long_500k skipped
         notes="GQA kv=2; code workload",
         source="arXiv:2402.19173",
     )

@@ -34,7 +34,7 @@ class Symptom:
     domains: Domain
     likely_causes: tuple[str, ...]
     transience: Transience
-    # What this maps to on the TPU-pod target (DESIGN.md §3 hardware adaptation)
+    # What this maps to on a TPU pod (the hardware this repo adapts to)
     tpu_analogue: str
     # severity for scheduler handling: "high" -> drain node immediately and
     # reschedule its jobs; "low" -> remediate after the running job finishes

@@ -1,7 +1,7 @@
 """Fabric topology model: 2-D torus of nodes with per-link state.
 
 The paper's clusters use a rail-optimized InfiniBand Clos; the TPU-idiomatic
-equivalent (DESIGN.md §3) is a torus ICI fabric where link failures are
+equivalent is a torus ICI fabric where link failures are
 routed *around* rather than through switch-level rerouting.  Links carry a
 health state: healthy, degraded (bit errors -> retransmissions -> reduced
 effective capacity), or down.

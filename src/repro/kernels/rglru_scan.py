@@ -5,7 +5,7 @@ h_t = a_t * h_{t-1} + sqrt(1 - a_t^2) * x_t, with data-dependent a_t.
 TPU adaptation: width is tiled into lane-aligned blocks (the recurrence is
 elementwise over width, so the grid parallelizes (batch, width-block) and
 iterates time-chunks sequentially with the (block_w,) hidden state in VMEM.
-Contrast with the associative-scan formulation used on the dry-run path
+Contrast with the associative-scan formulation used on other platforms
 (ops.rglru): the parallel scan is O(S log S) elementwise work and
 materializes two (B,S,W) intermediates; the kernel is O(S) with the state
 in VMEM and is the preferred form once S*W no longer fits in HBM headroom.

@@ -140,15 +140,16 @@ def self_attention(
     )
     o = constrain(o, "act_batch", "act_seq", "act_heads", None)
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
-    # NOTE (§Perf refuted hypothesis): constraining this output to the
-    # sequence-parallel layout, hoping for a reduce-scatter lowering,
-    # regressed granite -10% and broke the MoE dispatch path (see
-    # EXPERIMENTS.md §Perf round 3) — outputs stay seq-replicated and the
-    # boundary constraint in run_groups does the SP transition.
+    # Constraining this output to the sequence-parallel layout, hoping for
+    # a reduce-scatter lowering, cost granite's sharded step 10% (a CPU
+    # roofline estimate from compiled HLO, not a chip time) and broke the
+    # MoE dispatch path: outputs stay seq-replicated and the boundary
+    # constraint in run_groups does the SP transition.
     out = constrain(out, "act_batch", "act_seq", None)
     if cfg.remat_policy == "save_attn":
-        # the inert name primitive blocks gather-reuse fusions (§Perf:
-        # +10% all-gather on granite) — only tag when the policy uses it
+        # the inert name primitive blocks gather-reuse fusions (10% more
+        # all-gather in granite's sharded HLO): only tag when the policy
+        # uses it
         from jax.ad_checkpoint import checkpoint_name
         out = checkpoint_name(out, "attn_out")
     return out, (k, v)

@@ -3,14 +3,14 @@
 A model is described by a pytree of :class:`ParamDef`.  From that single
 source of truth we derive
   * concrete initialized parameters (smoke tests, real training),
-  * abstract ``jax.ShapeDtypeStruct`` stand-ins (dry-run lowering),
+  * abstract ``jax.ShapeDtypeStruct`` stand-ins (lowering and compiling
+    with no allocation, as the compile rehearsals do),
   * per-parameter ``NamedSharding`` (via logical-axis rules).
 
 Sharding resolution is *shape aware*: a mesh axis that does not evenly
 divide the corresponding dimension is dropped (e.g. MQA's single KV head
 cannot be sharded over a 16-way model axis; seamless' 256206 vocab is not
-divisible by 16).  Dropped axes are recorded so the roofline report can
-call them out.
+divisible by 16).
 """
 from __future__ import annotations
 
@@ -86,10 +86,10 @@ def abstract(defs):
 from repro.parallel.axes import spec_for  # shape-aware spec resolution
 
 
-def shardings(defs, mesh: Mesh, rules: ShardingRules, dropped: Optional[list] = None):
+def shardings(defs, mesh: Mesh, rules: ShardingRules):
     """Pytree of NamedSharding matching ``defs``."""
     return jax.tree_util.tree_map(
-        lambda d: NamedSharding(mesh, spec_for(d.shape, d.axes, mesh, rules, dropped)),
+        lambda d: NamedSharding(mesh, spec_for(d.shape, d.axes, mesh, rules)),
         defs,
         is_leaf=lambda x: isinstance(x, ParamDef),
     )

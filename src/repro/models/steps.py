@@ -1,7 +1,8 @@
-"""The three step functions the launcher lowers: train / prefill / decode.
+"""The three step functions: train / prefill / decode.
 
-Each ``make_*`` returns a pure function suitable for ``jax.jit`` with
-explicit in/out shardings (see ``repro.launch.dryrun``).
+Each ``make_*`` returns a pure function for ``jax.jit``: the trainer and the
+server jit them on one chip, and under a mesh context the same functions
+take the shardings of their placed arguments.
 """
 from __future__ import annotations
 

@@ -3,8 +3,8 @@
 Layers are executed as ``lax.scan`` over *block groups* (see configs.base)
 so lowered HLO size is independent of depth.  The same layer code serves
 training (full sequence), prefill (full sequence + cache write) and decode
-(one token + cache update), which keeps the three dry-run step functions
-consistent by construction.
+(one token + cache update), which keeps the three step functions
+(``models.steps``) consistent by construction.
 """
 from __future__ import annotations
 

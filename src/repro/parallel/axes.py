@@ -147,8 +147,7 @@ def current_rules() -> Optional[ShardingRules]:
     return _CTX.rules
 
 
-def spec_for(shape, axes, mesh: Mesh, rules: ShardingRules,
-             dropped: Optional[list] = None) -> P:
+def spec_for(shape, axes, mesh: Mesh, rules: ShardingRules) -> P:
     """Shape-aware PartitionSpec: drops mesh axes that don't divide dims or
     are absent from the mesh (e.g. "pod" on a single-pod mesh)."""
     import numpy as np
@@ -168,8 +167,6 @@ def spec_for(shape, axes, mesh: Mesh, rules: ShardingRules,
             if dim % (prod * mesh.shape[a]) == 0:
                 keep.append(a)
                 prod *= mesh.shape[a]
-            elif dropped is not None:
-                dropped.append((ax, a, dim))
         if not keep:
             entries.append(None)
             continue

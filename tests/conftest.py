@@ -1,9 +1,8 @@
 """Test session config.
 
-NOTE: tests intentionally see the single real CPU device — the 512-device
-flag belongs exclusively to the dry-run (repro.launch.dryrun).  Tests that
-need a multi-device mesh (pipeline, elastic, sharding) spawn subprocesses
-with their own XLA_FLAGS.
+NOTE: tests intentionally see the single real CPU device.  Tests that need
+a multi-device mesh (pipeline, elastic, sharding) spawn subprocesses with
+their own XLA_FLAGS.
 """
 import os
 import sys

@@ -1,7 +1,7 @@
 """Config registry: every assigned architecture loads with exact dims."""
 import pytest
 
-from repro.configs.base import SHAPES, get_arch, list_archs, smoke_config
+from repro.configs.base import get_arch, list_archs, smoke_config
 
 ASSIGNED = {
     "granite-20b": dict(n_layers=52, d_model=6144, n_heads=48, n_kv_heads=1,
@@ -86,16 +86,6 @@ def test_moe_active_params_less_than_total():
     assert abs(get_arch("mellum2-12b").active_param_count() - 2.5e9) < 0.1e9
 
 
-def test_long_context_flags():
-    runs = {n for n in ASSIGNED if get_arch(n).long_context_ok}
-    assert runs == {"gemma3-4b", "recurrentgemma-9b", "rwkv6-7b",
-                    "llama4-scout-17b-a16e", "mixtral-8x22b"}
-
-
-def test_shapes_table():
-    assert SHAPES["train_4k"].tokens == 4096 * 256
-    assert SHAPES["long_500k"].seq_len == 524_288
-    assert SHAPES["decode_32k"].kind == "decode"
 
 
 @pytest.mark.parametrize("name", sorted(ASSIGNED))

@@ -7,9 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as fak
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import block_class
-from repro.kernels.flash_attention import flash_attention as flash_pallas
 from repro.kernels.rglru_scan import rglru as rglru_pallas
 from repro.kernels.rwkv6_scan import wkv6 as wkv6_pallas
 from tests.conftest import run_subprocess_py
@@ -43,19 +43,47 @@ SWEEP = [
 ]
 
 
+def _lse_ref(q, k, *, causal, window, chunk, softcap):
+    """The oracle's per-row log-sum-exp, (B, H, Sq, 1) with h = kv * G + g."""
+    B, Sq, H, D = q.shape
+    _, Sk, KV, _ = k.shape
+    qf = q.astype(jnp.float32).reshape(B, Sq, KV, H // KV, D)
+    s = jnp.einsum("bqkgd,bskd->bkgqs", qf, k.astype(jnp.float32)) / np.sqrt(D)
+    if softcap > 0:
+        s = jnp.tanh(s / softcap) * softcap
+    m = ref._mask(jnp.arange(Sq), jnp.arange(Sk), causal=causal,
+                  window=window, chunk=chunk)
+    s = jnp.where(m, s, ref.NEG_INF)
+    return jax.nn.logsumexp(s, axis=-1).reshape(B, H, Sq, 1)
+
+
+# the two forwards: (o, lse) at blocks of 128
+FORWARDS = {
+    "pallas": lambda q, k, v, **kw: fak.pallas_forward(
+        q, k, v, block_q=128, block_k=128, interpret=True, **kw),
+    "blockwise": lambda q, k, v, **kw: fak.blockwise_forward(
+        q, k, v, block_q=128, block_k=128, **kw),
+}
+
+
+@pytest.mark.parametrize("forward", list(FORWARDS))
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("case", SWEEP)
-def test_pallas_flash_matches_oracle(case, dtype):
+def test_pallas_flash_matches_oracle(case, dtype, forward):
+    """Each forward's o and lse against the oracle: the backward reads
+    either forward's lse."""
     B, S, H, KV, D, causal, window, chunk, softcap = case
     q, k, v = _qkv(B, S, H, KV, D, dtype)
-    got = flash_pallas(q, k, v, causal=causal, window=window, chunk=chunk,
-                       softcap=softcap, block_q=128, block_k=128,
-                       interpret=True)
-    want = ref.attention_ref(q, k, v, causal=causal, window=window,
-                             chunk=chunk, softcap=softcap)
+    mode = dict(causal=causal, window=window, chunk=chunk, softcap=softcap)
+    got, lse = FORWARDS[forward](q, k, v, **mode)
+    want = ref.attention_ref(q, k, v, **mode)
     tol = 2e-6 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=tol)
+    assert lse.shape == (B, H, S, 1) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse),
+                               np.asarray(_lse_ref(q, k, **mode)),
+                               rtol=1e-6, atol=1e-6)
 
 
 @pytest.mark.parametrize("causal,window,chunk", [
@@ -87,34 +115,33 @@ def test_flash_block_class_matches_element_mask(causal, window, chunk):
         assert seen == {"full"}
 
 
-@pytest.mark.parametrize("case", SWEEP[:4])
-def test_jnp_flash_matches_oracle(case):
-    B, S, H, KV, D, causal, window, chunk, softcap = case
-    q, k, v = _qkv(B, S, H, KV, D, jnp.float32)
-    got = ops._flash(q, k, v, causal, window, chunk, softcap, 0, 128, 128)
-    want = ref.attention_ref(q, k, v, causal=causal, window=window,
-                             chunk=chunk, softcap=softcap)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+# the two differentiable wrappers, one custom_vjp: the forward differs,
+# the backward is the same
+ATTENTIONS = {
+    "pallas": lambda q, k, v, **kw: fak.flash_attention(
+        q, k, v, block_q=128, block_k=128, interpret=True, **kw),
+    "blockwise": lambda q, k, v, **kw: fak.blockwise_attention(
+        q, k, v, block_q=128, block_k=128, **kw),
+}
 
 
+@pytest.mark.parametrize("forward", list(ATTENTIONS))
 @pytest.mark.parametrize("case", [SWEEP[0], SWEEP[2], SWEEP[3], SWEEP[4],
                                   *SWEEP[6:]])
-def test_pallas_flash_vjp_matches_oracle_grads(case):
-    """Pallas forward (+ lse) with the blockwise-jnp backward."""
+def test_pallas_flash_vjp_matches_oracle_grads(case, forward):
+    """Either forward (+ lse) with the blockwise-jnp backward."""
     B, S, H, KV, D, causal, window, chunk, softcap = case
     q, k, v = _qkv(B, S, H, KV, D, jnp.float32)
     do = jax.random.normal(KEY, (B, S, H, D), jnp.float32)
+    mode = dict(causal=causal, window=window, chunk=chunk, softcap=softcap)
 
-    def f_pl(q, k, v):
-        return (flash_pallas(q, k, v, causal=causal, window=window,
-                             chunk=chunk, softcap=softcap, block_q=128,
-                             block_k=128, interpret=True) * do).sum()
+    def f_fl(q, k, v):
+        return (ATTENTIONS[forward](q, k, v, **mode) * do).sum()
 
     def f_ref(q, k, v):
-        return (ref.attention_ref(q, k, v, causal=causal, window=window,
-                                  chunk=chunk, softcap=softcap) * do).sum()
+        return (ref.attention_ref(q, k, v, **mode) * do).sum()
 
-    g1 = jax.grad(f_pl, argnums=(0, 1, 2))(q, k, v)
+    g1 = jax.grad(f_fl, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
@@ -156,30 +183,62 @@ def test_pallas_flash_sharded_under_mesh_subprocess():
     assert "OK" in r.stdout, r.stderr[-3000:]
 
 
-@pytest.mark.parametrize("case", [SWEEP[0], SWEEP[3], SWEEP[4]])
-def test_flash_custom_vjp_matches_oracle_grads(case):
-    B, S, H, KV, D, causal, window, chunk, softcap = case
-    q, k, v = _qkv(B, S, H, KV, D, jnp.float32)
-    do = jax.random.normal(KEY, (B, S, H, D), jnp.float32)
+def test_flash_under_mesh_with_indivisible_heads_subprocess():
+    """6 heads on a 4-way model axis, which they do not divide: under a mesh
+    the blockwise path is partitioned by XLA like any other op, and its
+    forward and grads match the oracle."""
+    code = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        import jax, jax.numpy as jnp
+        from repro.kernels import ops, ref
+        from repro.parallel.axes import mesh_context, TRAIN_RULES
 
-    def f_fl(q, k, v):
-        return (ops._flash(q, k, v, causal, window, chunk, softcap, 0,
-                           128, 128) * do).sum()
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        B, S, H, KV, D = 2, 2048, 6, 2, 64
+        ks = jax.random.split(jax.random.PRNGKey(0), 4)
+        q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
+        k = jax.random.normal(ks[1], (B, S, KV, D), jnp.float32)
+        v = jax.random.normal(ks[2], (B, S, KV, D), jnp.float32)
+        do = jax.random.normal(ks[3], (B, S, H, D), jnp.float32)
 
-    def f_ref(q, k, v):
-        return (ref.attention_ref(q, k, v, causal=causal, window=window,
-                                  chunk=chunk, softcap=softcap) * do).sum()
+        def f(q, k, v):
+            return (ops.flash_attention(q, k, v, causal=True) * do).sum()
+        with mesh_context(mesh, TRAIN_RULES), jax.set_mesh(mesh):
+            o = jax.jit(lambda q, k, v: ops.flash_attention(
+                q, k, v, causal=True))(q, k, v)
+            g = jax.jit(jax.grad(f, argnums=(0, 1, 2)))(q, k, v)
+        want = ref.attention_ref(q, k, v, causal=True)
+        def fr(q, k, v):
+            return (ref.attention_ref(q, k, v, causal=True) * do).sum()
+        gw = jax.grad(fr, argnums=(0, 1, 2))(q, k, v)
+        assert float(jnp.max(jnp.abs(o - want))) < 5e-6
+        for a, b in zip(g, gw):
+            assert float(jnp.max(jnp.abs(a - b))) < 5e-5
+        print("OK")
+    """)
+    r = run_subprocess_py(code, timeout=600)
+    assert "OK" in r.stdout, r.stderr[-3000:]
 
-    g1 = jax.grad(f_fl, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+
+def test_cp_inactive_without_mesh():
+    """Outside a mesh context, flash_attention at 2048 takes the blockwise
+    path and needs no shard_map."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (1, 2048, 6, 64), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 2048, 2, 64), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 2048, 2, 64), jnp.float32)
+    o = ops.flash_attention(q, k, v, causal=True)
+    want = ref.attention_ref(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want), atol=5e-6)
 
 
 def test_flash_softcap():
     B, S, H, KV, D = 1, 256, 2, 2, 64
     q, k, v = _qkv(B, S, H, KV, D, jnp.float32)
-    got = ops._flash(q, k, v, True, 0, 0, 30.0, 0, 128, 128)
+    got = fak.blockwise_attention(q, k, v, causal=True, softcap=30.0,
+                                  block_q=128, block_k=128)
     want = ref.attention_ref(q, k, v, causal=True, softcap=30.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
 

@@ -1,6 +1,6 @@
 """End-to-end behaviour: the paper's pipeline from cluster data to models
-and mitigations, plus a small-mesh dry-run of the launch path."""
-import json
+and mitigations, plus the train and decode steps compiled on a small
+mesh."""
 import textwrap
 
 import numpy as np
@@ -63,62 +63,62 @@ def test_attribution_mix_dominated_by_fig4_modes(sim):
                       "gpu_memory_errors", "pcie_errors", "gpu_unavailable"}
 
 
-def test_small_mesh_dryrun_subprocess():
-    """The launch path (specs + shardings + lower + compile + analyses)
-    works on a small forced mesh for a dense and a MoE arch."""
+def test_small_mesh_steps_compile_subprocess():
+    """The train step and the decode step of a dense and a MoE arch compile
+    on a small (pod, data, model) mesh, parameters and optimizer state
+    placed by ``reshard_for`` under TRAIN_RULES as the four-chip path places
+    them; the train step's program holds a cross-device collective."""
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import jax, dataclasses
-        from repro.configs.base import get_arch, smoke_config, ShapeSpec
-        from repro.launch import specs, hlo_analysis
+        import re
+        import jax, jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.configs.base import get_arch, smoke_config
         from repro.launch.mesh import make_test_mesh
-        from repro.models.steps import make_train_step, make_decode_step
+        from repro.models import params as pmod
+        from repro.models import transformer
+        from repro.models.steps import (make_decode_step, make_prefill_step,
+                                        make_train_step)
         from repro.optim import adamw
-        from repro.parallel.axes import mesh_context
+        from repro.parallel.axes import TRAIN_RULES, mesh_context
+        from repro.runtime.elastic import reshard_for
 
         mesh = make_test_mesh(data=2, model=2, pod=2)
+        rep = NamedSharding(mesh, P())
+        rows = NamedSharding(mesh, P(("pod", "data")))
         for arch in ("qwen3-0.6b", "mixtral-8x22b"):
             cfg = smoke_config(get_arch(arch))
-            shape = ShapeSpec("train_4k", "train", 64, 8)
-            rules = specs.rules_for(shape)
-            args = specs.input_specs(cfg, shape)
-            in_sh = specs.input_shardings(cfg, shape, mesh, rules)
-            out_sh = specs.output_shardings(cfg, shape, mesh, rules)
-            fn = make_train_step(cfg, adamw.AdamWConfig())
-            with mesh_context(mesh, rules):
-                c = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
-                            donate_argnums=(0, 1)).lower(*args).compile()
-            mod = hlo_analysis.analyze_module(c.as_text(), pod_size=4)
-            assert mod["flops"] > 0 and mod["bytes"] > 0
-            assert mod["collectives"]["total_bytes"] > 0, arch
-            # decode path
-            shape_d = ShapeSpec("decode_32k", "decode", 128, 8)
-            args_d = specs.input_specs(cfg, shape_d)
-            in_d = specs.input_shardings(cfg, shape_d, mesh)
-            out_d = specs.output_shardings(cfg, shape_d, mesh)
-            fn_d = make_decode_step(cfg)
-            with mesh_context(mesh, specs.rules_for(shape_d)):
-                cd = jax.jit(fn_d, in_shardings=in_d, out_shardings=out_d,
-                             donate_argnums=(1,)).lower(*args_d).compile()
+            defs = transformer.model_defs(cfg)
+            params = pmod.materialize(defs, seed=0)
+            opt = adamw.init(params)
+            placed = (reshard_for(params, mesh, TRAIN_RULES, defs),
+                      adamw.AdamWState(
+                          jax.device_put(opt.step, rep),
+                          reshard_for(opt.m, mesh, TRAIN_RULES, defs),
+                          reshard_for(opt.v, mesh, TRAIN_RULES, defs)))
+            batch = {"tokens": jax.device_put(
+                jnp.zeros((8, 65), jnp.int32), rows)}
+            step = make_train_step(cfg, adamw.AdamWConfig())
+            with mesh_context(mesh, TRAIN_RULES):
+                c = jax.jit(step, donate_argnums=(0, 1)).lower(
+                    *placed, batch).compile()
+            assert re.search(r"all-reduce|all-gather|reduce-scatter|"
+                             r"all-to-all|collective-permute",
+                             c.as_text()), arch
+            # decode, against a cache sized as prefill leaves it
+            sdefs = pmod.cast_defs(defs, jnp.bfloat16)
+            sparams = reshard_for(pmod.materialize(sdefs, seed=0), mesh,
+                                  TRAIN_RULES, sdefs)
+            tokens = jax.device_put(jnp.zeros((8, 1), jnp.int32), rows)
+            with mesh_context(mesh, TRAIN_RULES):
+                cache = jax.eval_shape(make_prefill_step(cfg, cache_len=128),
+                                       sparams, {"tokens": jnp.zeros(
+                                           (8, 120), jnp.int32)})[1]
+                cd = jax.jit(make_decode_step(cfg), donate_argnums=(1,)).lower(
+                    sparams, cache, tokens).compile()
             assert cd.memory_analysis().temp_size_in_bytes >= 0
             print("OK", arch)
     """)
     r = run_subprocess_py(code, timeout=900)
     assert r.stdout.count("OK") == 2, r.stderr[-3000:]
-
-
-def test_dryrun_results_coverage(repo_root):
-    """If the full 40-cell sweep has been run, every cell is accounted for."""
-    import glob
-    import os
-
-    files = glob.glob(os.path.join(repo_root, "results", "dryrun", "*.json"))
-    if len(files) < 80:
-        pytest.skip("full dry-run sweep not present")
-    recs = [json.load(open(f)) for f in files]
-    assert len(recs) == 80
-    assert all(r["status"] in ("ok", "skipped_full_attention") for r in recs)
-    skips = [r for r in recs if r["status"] == "skipped_full_attention"]
-    assert len(skips) == 10  # 5 archs x 2 meshes, long_500k only
-    assert all(r["shape"] == "long_500k" for r in skips)
