@@ -79,7 +79,7 @@ def pallas_flash(q, k, v, *, interpret: bool = False, **kw) -> jax.Array:
 # ---------------------------------------------------------------------------
 def decode_attention(
     q: jax.Array,         # (B, 1, H, D)
-    k_cache: jax.Array,   # (B, L, KV, D)
+    k_cache: jax.Array,   # (L, KV, B, D)
     v_cache: jax.Array,
     slot_pos: jax.Array,  # (B, L)
     pos: jax.Array,       # (B,)

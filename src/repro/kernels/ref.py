@@ -60,8 +60,8 @@ def attention_ref(
 
 def decode_attention_ref(
     q: jax.Array,        # (B, 1, H, D)
-    k_cache: jax.Array,  # (B, L, KV, D)
-    v_cache: jax.Array,  # (B, L, KV, D)
+    k_cache: jax.Array,  # (L, KV, B, D)
+    v_cache: jax.Array,  # (L, KV, B, D)
     slot_pos: jax.Array,  # (B, L) absolute position per slot, -1 = empty
     pos: jax.Array,      # (B,) current query position
     *,
@@ -70,10 +70,10 @@ def decode_attention_ref(
     softcap: float = 0.0,
 ) -> jax.Array:
     B, _, H, D = q.shape
-    _, L, KV, _ = k_cache.shape
+    L, KV, _, _ = k_cache.shape
     G = H // KV
     qf = q.astype(jnp.float32).reshape(B, KV, G, D)
-    s = jnp.einsum("bkgd,blkd->bkgl", qf, k_cache.astype(jnp.float32)) / np.sqrt(D)
+    s = jnp.einsum("bkgd,lkbd->bkgl", qf, k_cache.astype(jnp.float32)) / np.sqrt(D)
     if softcap > 0:
         s = jnp.tanh(s / softcap) * softcap
     valid = (slot_pos >= 0) & (slot_pos <= pos[:, None])
@@ -84,7 +84,7 @@ def decode_attention_ref(
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(valid.any(-1)[:, None, None, None], p, 0.0)
-    o = jnp.einsum("bkgl,blkd->bkgd", p, v_cache.astype(jnp.float32))
+    o = jnp.einsum("bkgl,lkbd->bkgd", p, v_cache.astype(jnp.float32))
     return o.reshape(B, 1, H, D).astype(q.dtype)
 
 

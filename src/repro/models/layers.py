@@ -172,18 +172,24 @@ def decode_self_attention(
     x: jax.Array,  # (B, 1, d)
     cfg: ArchConfig,
     kind: str,
-    k_cache: jax.Array,  # (B, L, KV, Dh)
+    k_cache: jax.Array,  # (layers, L, KV, B, Dh): every repeat's
     v_cache: jax.Array,
     pos: jax.Array,  # scalar int32 current position
+    layer: jax.Array,  # scalar int32: this layer's index in the stack
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token attention; returns (out, new_k_cache, new_v_cache)."""
+    """One-token attention; returns (out, new_k_cache, new_v_cache), the
+    stacks with this layer's slot for ``pos`` written in place."""
     B, _, _ = x.shape
     L = k_cache.shape[1]
     positions = pos[None]  # (1,)
     q, k, v = _project_qkv(p, x, x, cfg, positions, kind)
     slot = pos % L  # ring slot (== pos for a full-length global cache)
-    k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0))
+    at = (layer, slot, 0, 0, 0)
+    # (B, 1, KV, Dh) -> (1, 1, KV, B, Dh)
+    k_cache = jax.lax.dynamic_update_slice(
+        k_cache, k.transpose(1, 2, 0, 3)[None].astype(k_cache.dtype), at)
+    v_cache = jax.lax.dynamic_update_slice(
+        v_cache, v.transpose(1, 2, 0, 3)[None].astype(v_cache.dtype), at)
     # absolute position stored in each slot of a ring buffer
     idx = jnp.arange(L)
     if kind == "global":
@@ -195,7 +201,8 @@ def decode_self_attention(
     window = cfg.window if kind == "local" else 0
     chunk = cfg.window if kind == "chunked" else 0
     o = ops.decode_attention(
-        q, k_cache, v_cache, slot_pos, jnp.broadcast_to(pos[None], (B,)),
+        q, k_cache[layer], v_cache[layer], slot_pos,
+        jnp.broadcast_to(pos[None], (B,)),
         window=window, chunk=chunk, softcap=cfg.attn_logit_softcap,
     )
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))

@@ -143,7 +143,9 @@ def apply_layer(cfg, kind, p, h, *, causal=True, positions=None, enc_out=None,
     k, v = k[:, -L:], v[:, -L:]
     if S % L:  # a ring holds position p in slot p % L, where decode looks
         k, v = jnp.roll(k, S % L, axis=1), jnp.roll(v, S % L, axis=1)
-    cache = {"k": k.astype(COMPUTE_DTYPE), "v": v.astype(COMPUTE_DTYPE)}
+    # stored (L, KV, B, D), the order decode's attention reads
+    cache = {n: x.transpose(1, 2, 0, 3).astype(COMPUTE_DTYPE)
+             for n, x in (("k", k), ("v", v))}
     if aux is None:
         aux_vec = _aux_zeros()
     else:
@@ -175,36 +177,39 @@ def _decode_cross_attention(p, x, xk, xv, cfg):
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(dt))
 
 
-def decode_apply_layer(cfg, kind, p, h, cache, pos, experts=None, layer=None):
-    """One-token layer application. Returns (h, new_cache).  ``experts``
-    and ``layer`` go to ``moe_ffn`` (see ``_layer_scan``)."""
-    if kind == "rwkv":
-        h, state = recurrent.rwkv_block(p, h, cfg, state=cache)
-        return h, state
-    if kind == "rglru":
-        h, state = recurrent.rglru_block(p, h, cfg, state=cache)
-        return h, state
+def decode_apply_layer(cfg, kind, p, h, cache, pos, layer, experts=None):
+    """One-token layer application against a group's stacked cache entry
+    ``cache`` (every repeat's, leading axis ``layers``), of which this is
+    repeat ``layer``.  Returns (h, cache): the entry with this repeat's
+    K/V slot, recurrent state or counters written in place, and the rest
+    as it came.  ``experts`` goes to ``moe_ffn`` (see ``_layer_scan``)."""
+    if kind in ("rwkv", "rglru"):
+        block = {"rwkv": recurrent.rwkv_block,
+                 "rglru": recurrent.rglru_block}[kind]
+        h, state = block(p, h, cfg,
+                         state={k: x[layer] for k, x in cache.items()})
+        return h, {k: x.at[layer].set(state[k].astype(x.dtype))
+                   for k, x in cache.items()}
     a_out, k_c, v_c = decode_self_attention(
         p["attn"], rms_norm(h, p["ln1"], cfg.norm_eps), cfg, kind,
-        cache["k"], cache["v"], pos,
+        cache["k"], cache["v"], pos, layer,
     )
     h = h + a_out
-    new_cache = dict(cache)
-    new_cache["k"], new_cache["v"] = k_c, v_c
+    cache = dict(cache, k=k_c, v=v_c)
     if "xk" in cache:
         h = h + _decode_cross_attention(
             p["xattn"], rms_norm(h, p["ln_x"], cfg.norm_eps),
-            cache["xk"], cache["xv"], cfg)
+            cache["xk"][layer], cache["xv"][layer], cfg)
     hn = rms_norm(h, p["ln2"], cfg.norm_eps)
     if cfg.moe is not None:
         f_out, aux = moe_ffn(p["moe"], hn, cfg, experts=experts, layer=layer)
         # routing counters: routes per expert, and distinct experts a step
-        new_cache["routed"] = cache["routed"] + aux["routed"]
-        new_cache["touched"] = cache["touched"] + jnp.sum(
-            aux["routed"] > 0, dtype=jnp.int32)
+        cache["routed"] = cache["routed"].at[layer].add(aux["routed"])
+        cache["touched"] = cache["touched"].at[layer].add(
+            jnp.sum(aux["routed"] > 0, dtype=jnp.int32))
     else:
         f_out = ffn(p["ffn"], hn)
-    return h + f_out, new_cache
+    return h + f_out, cache
 
 
 # ---------------------------------------------------------------------------
@@ -227,10 +232,10 @@ def _remat(fn, cfg: ArchConfig):
 EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
-def _layer_scan(cfg: ArchConfig, repeats: int, gparams):
-    """What a group's layer scan runs over, and ``fetch(xs, i)``, which
-    gives the body the params of the pattern's layer i and the keyword
-    arguments its layer application takes beside them.
+def _layer_scan(cfg: ArchConfig, gparams):
+    """What of a group's params its layer scan runs over, and ``held(i)``:
+    the keyword arguments the pattern's layer i takes beside its params
+    and its repeat's index ``layer`` (the scan's other ``xs``).
 
     A dropless MoE layer's expert weights stay out of the scan, whole:
     its grouped matmul takes them as a kernel operand, and a per-layer
@@ -239,17 +244,13 @@ def _layer_scan(cfg: ArchConfig, repeats: int, gparams):
     body hands them on stacked, as ``experts``, with the repeat's index
     as ``layer``."""
     if cfg.moe is None or not cfg.moe.dropless:
-        return gparams, lambda xs, i: (xs[f"p{i}"], {})
+        return gparams, lambda i: {}
     held = {k: {w: p["moe"][w] for w in EXPERT_WEIGHTS}
             for k, p in gparams.items()}
     rest = {k: dict(p, moe={w: v for w, v in p["moe"].items()
                             if w not in EXPERT_WEIGHTS})
             for k, p in gparams.items()}
-
-    def fetch(xs, i):
-        return xs[0][f"p{i}"], {"experts": held[f"p{i}"], "layer": xs[1]}
-
-    return (rest, jnp.arange(repeats)), fetch
+    return rest, lambda i: {"experts": held[f"p{i}"]}
 
 
 def run_groups(params_groups, cfg: ArchConfig, h, *, causal=True,
@@ -258,57 +259,49 @@ def run_groups(params_groups, cfg: ArchConfig, h, *, causal=True,
     aux = _aux_zeros()
     caches = []
     for (pattern, repeats), gparams in zip(cfg.block_groups, params_groups):
-        gparams, fetch = _layer_scan(cfg, repeats, gparams)
-        if collect_cache:
-            def body(carry, xs):
-                hh, av = carry
-                hh = constrain(hh, "act_batch", "act_res_seq", None)
-                cache_out = {}
-                for i, kind in enumerate(pattern):
-                    p, held = fetch(xs, i)
-                    hh, a, c = apply_layer(
-                        cfg, kind, p, hh, causal=causal,
-                        positions=positions, enc_out=enc_out, **held)
-                    av = av + a
-                    cache_out[f"p{i}"] = c
-                return (hh, av), cache_out
+        gparams, held = _layer_scan(cfg, gparams)
 
-            (h, aux), cache_g = jax.lax.scan(_remat(body, cfg), (h, aux), gparams)
-            caches.append(cache_g)
-        else:
-            def body(carry, xs):
-                hh, av = carry
-                hh = constrain(hh, "act_batch", "act_res_seq", None)
-                for i, kind in enumerate(pattern):
-                    p, held = fetch(xs, i)
-                    hh, a, _ = apply_layer(
-                        cfg, kind, p, hh, causal=causal,
-                        positions=positions, enc_out=enc_out, **held)
-                    av = av + a
-                return (hh, av), None
+        def body(carry, xs):
+            hh, av = carry
+            p, r = xs
+            hh = constrain(hh, "act_batch", "act_res_seq", None)
+            cache_out = {}
+            for i, kind in enumerate(pattern):
+                hh, a, c = apply_layer(
+                    cfg, kind, p[f"p{i}"], hh, causal=causal,
+                    positions=positions, enc_out=enc_out, layer=r, **held(i))
+                av = av + a
+                cache_out[f"p{i}"] = c
+            return (hh, av), (cache_out if collect_cache else None)
 
-            (h, aux), _ = jax.lax.scan(_remat(body, cfg), (h, aux), gparams)
+        (h, aux), cache_g = jax.lax.scan(
+            _remat(body, cfg), (h, aux), (gparams, jnp.arange(repeats)))
+        caches.append(cache_g)
     return h, aux, (caches if collect_cache else None)
 
 
 def run_groups_decode(params_groups, cfg: ArchConfig, h, cache_groups, pos):
+    """One token through all block groups.  Each group's stacked cache is
+    carried through its layer scan whole and written where it lies (a K/V
+    slot, a recurrent state, a counter of the repeat the body runs); the
+    body's attention reads its repeat's K/V out of the stack."""
     new_caches = []
     for (pattern, repeats), gparams, gcache in zip(
             cfg.block_groups, params_groups, cache_groups):
-        gparams, fetch = _layer_scan(cfg, repeats, gparams)
+        gparams, held = _layer_scan(cfg, gparams)
 
-        def body(hh, xs):
-            p_slice, c_slice = xs
-            new_c = {}
+        def body(carry, xs):
+            hh, c = carry
+            p, r = xs
+            c = dict(c)
             for i, kind in enumerate(pattern):
-                p, held = fetch(p_slice, i)
-                hh, nc = decode_apply_layer(
-                    cfg, kind, p, hh, c_slice[f"p{i}"], pos, **held)
-                new_c[f"p{i}"] = nc
-            return hh, new_c
+                hh, c[f"p{i}"] = decode_apply_layer(
+                    cfg, kind, p[f"p{i}"], hh, c[f"p{i}"], pos, r, **held(i))
+            return (hh, c), None
 
-        h, new_cache_g = jax.lax.scan(body, h, (gparams, gcache))
-        new_caches.append(new_cache_g)
+        (h, gcache), _ = jax.lax.scan(
+            body, (h, gcache), (gparams, jnp.arange(repeats)))
+        new_caches.append(gcache)
     return h, new_caches
 
 
@@ -472,9 +465,10 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int, enc_len: int = 0):
                 ent = recurrent.rglru_init_state(cfg, batch)
             else:
                 L = cfg.kv_cache_len(kind, seq_len)
+                # K/V (L, KV, B, D), as prefill stores them
                 ent = {
-                    "k": jnp.zeros((batch, L, cfg.n_kv_heads, cfg.d_head), COMPUTE_DTYPE),
-                    "v": jnp.zeros((batch, L, cfg.n_kv_heads, cfg.d_head), COMPUTE_DTYPE),
+                    "k": jnp.zeros((L, cfg.n_kv_heads, batch, cfg.d_head), COMPUTE_DTYPE),
+                    "v": jnp.zeros((L, cfg.n_kv_heads, batch, cfg.d_head), COMPUTE_DTYPE),
                 }
                 if cfg.enc_dec:
                     se = enc_len or seq_len
@@ -490,7 +484,7 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int, enc_len: int = 0):
 
 
 def extend_cache(cfg: ArchConfig, caches: list, length: int) -> list:
-    """Size prefill's K/V caches (stacked (layers, B, S, KV, D)) for
+    """Size prefill's K/V caches (stacked (layers, S, KV, B, D)) for
     ``length`` positions: every global-attention layer's to ``length``
     slots, every windowed ring to the smaller of the window and
     ``length``.  The empty slots are masked by decode until it writes them
@@ -507,7 +501,7 @@ def extend_cache(cfg: ArchConfig, caches: list, length: int) -> list:
             for name in ("k", "v"):
                 x = ent[name]
                 ent[name] = jnp.pad(
-                    x, ((0, 0), (0, 0), (0, want - x.shape[2]), (0, 0),
+                    x, ((0, 0), (0, want - x.shape[1]), (0, 0), (0, 0),
                         (0, 0)))
             g[f"p{i}"] = ent
         out.append(g)
@@ -516,7 +510,8 @@ def extend_cache(cfg: ArchConfig, caches: list, length: int) -> list:
 
 def cache_axes(cfg: ArchConfig):
     """Logical-axis pytree matching init_cache's structure."""
-    kv = ("layers", "cache_batch", "cache_seq", "act_kv_heads", None)
+    kv = ("layers", "cache_seq", "act_kv_heads", "cache_batch", None)
+    xkv = ("layers", "cache_batch", "cache_seq", "act_kv_heads", None)
     groups = []
     for pattern, repeats in cfg.block_groups:
         g = {}
@@ -528,8 +523,8 @@ def cache_axes(cfg: ArchConfig):
             else:
                 ent = {"k": kv, "v": kv}
                 if cfg.enc_dec:
-                    ent["xk"] = kv
-                    ent["xv"] = kv
+                    ent["xk"] = xkv
+                    ent["xv"] = xkv
                 if cfg.moe is not None:
                     ent["routed"] = ("layers", None)
                     ent["touched"] = ("layers",)

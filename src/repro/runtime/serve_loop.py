@@ -41,6 +41,12 @@ class ServeReport:
     routes: Optional[dict] = None
 
 
+def decode_jit(cfg: ArchConfig):
+    """The decode step as ``Server`` runs it: jitted with its cache donated,
+    so that each step writes its K/V slots where they lie."""
+    return jax.jit(make_decode_step(cfg), donate_argnums=(1,))
+
+
 class Server:
     def __init__(self, cfg: ArchConfig, scfg: ServeConfig,
                  injector: Optional[FaultInjector] = None):
@@ -51,7 +57,7 @@ class Server:
         self.params = pmod.materialize(defs, seed=scfg.seed)
         self.prefill = jax.jit(make_prefill_step(
             cfg, cache_len=scfg.prompt_len + scfg.max_new_tokens))
-        self.decode = jax.jit(make_decode_step(cfg))
+        self.decode = decode_jit(cfg)
         self.batches = 0  # calls of run(): the spans' ``batch`` id
 
     def _requests(self) -> np.ndarray:
@@ -68,6 +74,7 @@ class Server:
             prompts = self._requests()
             retries = 0
             step_counter = 0
+            counted = []
             while True:
                 try:
                     batch = {"tokens": jnp.asarray(prompts)}
@@ -76,7 +83,6 @@ class Server:
                             (sc.batch, sc.prompt_len, self.cfg.d_model),
                             jnp.bfloat16)
                     logits, cache = self.prefill(self.params, batch)
-                    counted = cache
                     out = np.zeros((sc.batch, sc.max_new_tokens), np.int32)
                     tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
                     for i in range(sc.max_new_tokens):
@@ -84,10 +90,11 @@ class Server:
                         step_counter += 1
                         if fault is not None and fault.kind == "crash":
                             raise SimulatedFault(fault)
+                        if i == sc.max_new_tokens - 1:  # see ``routes``
+                            counted = _counters(cache)
                         # the step that consumes token i is queued before
                         # the host waits for token i, so the device runs
                         # from one step straight into the next
-                        counted = cache
                         with span("repro.serve.dispatch", batch=b, token=i,
                                   ahead=not tok.is_ready()):
                             logits, cache = self.decode(
@@ -107,23 +114,20 @@ class Server:
             tokens_generated=int(sc.batch * sc.max_new_tokens),
             wall_s=whole.seconds, outputs=out, routes=routes)
 
-    def routes(self, b: int, cache: dict, steps: int) -> Optional[dict]:
-        """An MoE model's routing counters in ``cache``, copied to the host
-        once and recorded as a ``repro.serve.moe`` span; None for a model
-        without experts.  ``cache`` is the last decode step's input, whose
-        counters hold the prefill and the ``steps`` decode steps before
+    def routes(self, b: int, counted: list, steps: int) -> Optional[dict]:
+        """An MoE model's routing counters ``counted`` (``_counters``),
+        copied to the host once and recorded as a ``repro.serve.moe`` span;
+        None for a model without experts.  They are the last decode step's
+        input's, holding the prefill and the ``steps`` decode steps before
         it: they were ready when the last token was, so the copy waits on
         nothing the loop did not (the batch's last, unused step runs on).
 
         ``routed`` (MoE layers, experts): routes each expert received;
         ``touched`` (MoE layers,): distinct experts each decode step read,
         summed over the steps."""
-        ents = [c for g in cache["groups"] for c in g.values()
-                if "routed" in c]
-        if not ents:
+        if not counted:
             return None
-        routed, touched = jax.device_get(
-            ([c["routed"] for c in ents], [c["touched"] for c in ents]))
+        routed, touched = jax.device_get(tuple(zip(*counted)))
         routed = np.concatenate(routed).astype(np.int64)  # (layers, E)
         touched = np.concatenate(touched).astype(np.int64)
         stats = {
@@ -135,3 +139,11 @@ class Server:
         }
         with span("repro.serve.moe", batch=b, **stats):
             return dict(stats, routed=routed, touched=touched)
+
+
+def _counters(cache: dict) -> list:
+    """Device copies of the MoE layers' (routed, touched) counters in
+    ``cache``, made before the cache is donated to the next decode step
+    (the copies are queued ahead of it, and wait on nothing else)."""
+    return [(jnp.copy(c["routed"]), jnp.copy(c["touched"]))
+            for g in cache["groups"] for c in g.values() if "routed" in c]

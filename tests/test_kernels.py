@@ -250,7 +250,9 @@ def test_decode_attention_consistent_with_full():
     full = ref.attention_ref(q, k, v, causal=True)
     slot_pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     pos = jnp.full((B,), S - 1)
-    dec = ref.decode_attention_ref(q[:, -1:], k, v, slot_pos, pos)
+    # the cache holds (L, KV, B, D), the order decode stores
+    dec = ref.decode_attention_ref(q[:, -1:], k.transpose(1, 2, 0, 3),
+                                   v.transpose(1, 2, 0, 3), slot_pos, pos)
     np.testing.assert_allclose(np.asarray(dec[:, 0]), np.asarray(full[:, -1]),
                                atol=2e-5)
 

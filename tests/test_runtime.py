@@ -239,12 +239,27 @@ def test_monitors_as_obs_metric_sources():
 
 
 # -- serving ------------------------------------------------------------------
-def test_server_retries_through_fault(cfg):
-    srv = Server(cfg, ServeConfig(batch=2, prompt_len=16, max_new_tokens=6),
+@pytest.fixture(params=["rsc-llm", "mellum2-12b"])
+def serve_cfg(request):
+    """A dense model, and a sparse-expert one whose routing counters ride
+    in the decode cache that each step donates."""
+    return smoke_config(get_arch(request.param))
+
+
+def test_server_retries_through_fault(serve_cfg):
+    """A crash mid-decode replays the batch from a new prefill: the cache
+    the crashed attempt donated to its steps is not read again, and the
+    replay serves the tokens and counts the routes of an attempt with no
+    crash."""
+    srv = Server(serve_cfg,
+                 ServeConfig(batch=2, prompt_len=16, max_new_tokens=6),
                  FaultInjector(schedule={2: InjectedFault("ib_link_error")}))
     rep = srv.run()
     assert rep.retries == 1
     assert rep.outputs.shape == (2, 6)
+    want, routes = _lockstep(srv)
+    assert np.array_equal(rep.outputs, want)
+    _assert_same_routes(rep.routes, routes)
 
 
 def test_server_output_deterministic(cfg):
@@ -257,25 +272,64 @@ def test_server_output_deterministic(cfg):
 
 def _lockstep(srv):
     """The served tokens of ``srv``'s batch by the plain loop: copy token
-    i to the host, then dispatch the decode step that consumes it."""
+    i to the host, then dispatch the decode step that consumes it, jitted
+    without donation so that every step's cache stays readable.  Also the
+    routing counters the batch reports (None for a dense model): the
+    prefill's, plus each of the first ``max_new_tokens - 1`` steps' own,
+    summed here."""
+    from repro.models.steps import make_decode_step
+
     sc = srv.scfg
+    decode = jax.jit(make_decode_step(srv.cfg))
     logits, cache = srv.prefill(srv.params,
                                 {"tokens": jnp.asarray(srv._requests())})
     out = np.zeros((sc.batch, sc.max_new_tokens), np.int32)
     tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+    caches = [cache]
     for i in range(sc.max_new_tokens):
         out[:, i] = np.asarray(tok)
-        logits, cache = srv.decode(srv.params, cache, tok[:, None])
+        logits, cache = decode(srv.params, cache, tok[:, None])
+        caches.append(cache)
         tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-    return out
+
+    def counters(c):
+        ents = [e for g in c["groups"] for e in g.values() if "routed" in e]
+        return (np.concatenate([np.asarray(e["routed"]) for e in ents]),
+                np.concatenate([np.asarray(e["touched"]) for e in ents]))
+
+    if srv.cfg.moe is None:
+        return out, None
+    routed, touched = counters(caches[0])
+    assert not touched.any()   # prefill routes; no decode step yet
+    for before, after in zip(caches[:sc.max_new_tokens - 1], caches[1:]):
+        (r0, t0), (r1, t1) = counters(before), counters(after)
+        step = r1 - r0   # one step's routes: top_k of each request's token
+        assert (step.sum(1) == sc.batch * srv.cfg.moe.top_k).all()
+        assert np.array_equal(t1 - t0, (step > 0).sum(1))
+        routed, touched = routed + step, touched + (step > 0).sum(1)
+    return out, {"routed": routed, "touched": touched,
+                 "decode_steps": sc.max_new_tokens - 1}
 
 
-def test_server_outputs_equal_the_lockstep_loop(cfg):
-    srv = Server(cfg, ServeConfig(batch=2, prompt_len=16, max_new_tokens=6))
-    first, second = srv.run().outputs, srv.run().outputs
-    want = _lockstep(srv)
-    assert first.dtype == want.dtype == np.int32
-    assert np.array_equal(first, want) and np.array_equal(second, want)
+def _assert_same_routes(got, want):
+    if want is None:
+        assert got is None
+        return
+    assert got["decode_steps"] == want["decode_steps"]
+    assert np.array_equal(got["routed"], want["routed"])
+    assert np.array_equal(got["touched"], want["touched"])
+
+
+def test_server_outputs_equal_the_lockstep_loop(serve_cfg):
+    srv = Server(serve_cfg,
+                 ServeConfig(batch=2, prompt_len=16, max_new_tokens=6))
+    first, second = srv.run(), srv.run()
+    want, routes = _lockstep(srv)
+    assert first.outputs.dtype == want.dtype == np.int32
+    assert np.array_equal(first.outputs, want)
+    assert np.array_equal(second.outputs, want)
+    _assert_same_routes(first.routes, routes)
+    _assert_same_routes(second.routes, routes)
 
 
 @pytest.mark.parametrize("crash_at", [None, 3])
@@ -307,36 +361,86 @@ def test_server_decodes_max_new_tokens_per_attempt(cfg, crash_at):
     assert calls == ["prefill"] + ["decode"] * T
 
 
-def test_decode_matches_full_forward_past_the_prompt():
-    """Decoding beyond the prompt keeps every earlier token in the global
-    KV cache: each decode step's logits equal a full forward pass over the
-    prompt plus the tokens generated so far (float32, own process)."""
+# the decode cache's kinds: (arch, config changes, prompt, new tokens).  A
+# window of 8 with a 13-token prompt puts prefill's ring out of order
+# (rolled) and makes decode wrap it.
+DECODE_CASES = {
+    "global-gqa": ("starcoder2-3b", {}, 16, 6),
+    "global-mqa": ("granite-20b", {}, 16, 6),
+    "local-ring": ("gemma3-4b", {"window": 8}, 13, 6),
+    "chunked": ("starcoder2-3b", {"block_groups": ((("chunked",), 2),),
+                                  "window": 8}, 13, 6),
+    "moe": ("mellum2-12b", {"window": 8}, 13, 6),
+    "rglru": ("recurrentgemma-9b", {"window": 8}, 13, 6),
+    "rwkv": ("rwkv6-7b", {}, 16, 6),
+    "enc-dec": ("seamless-m4t-large-v2", {}, 16, 6),
+}
+
+
+@pytest.fixture(scope="module")
+def decoded_past_the_prompt():
+    """Every case of DECODE_CASES run in one float32 process: {case:
+    "OK" or the failure}."""
+    import json
     import textwrap
 
     from tests.conftest import run_subprocess_py
 
     code = textwrap.dedent("""
-        import os
+        import json, os, traceback
         os.environ["REPRO_COMPUTE_DTYPE"] = "float32"
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs.base import get_arch, smoke_config
         from repro.models import params as pmod, transformer
         from repro.models.steps import make_decode_step, make_prefill_step
-        cfg = smoke_config(get_arch("starcoder2-3b"))
-        params = pmod.materialize(transformer.model_defs(cfg), seed=0)
-        S, N = 16, 6
-        toks = jax.random.randint(jax.random.PRNGKey(1), (2, S), 3,
-                                  cfg.vocab_size)
-        logits, cache = jax.jit(make_prefill_step(cfg, S + N))(
-            params, {"tokens": toks})
-        decode = jax.jit(make_decode_step(cfg))
-        for i in range(N):
-            tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-            toks = jnp.concatenate([toks, tok], axis=1)
-            logits, cache = decode(params, cache, tok)
-            full, _ = make_prefill_step(cfg)(params, {"tokens": toks})
-            np.testing.assert_allclose(logits, full, atol=1e-4, rtol=1e-4)
-        print("OK")
-    """)
-    r = run_subprocess_py(code, timeout=600)
-    assert "OK" in r.stdout, r.stderr[-3000:]
+        from repro.runtime.serve_loop import decode_jit
+
+        def case(arch, changes, S, N):
+            cfg = smoke_config(get_arch(arch)).replace(**changes)
+            params = pmod.materialize(transformer.model_defs(cfg), seed=0)
+            toks = jax.random.randint(jax.random.PRNGKey(1), (2, S), 3,
+                                      cfg.vocab_size)
+            extra = {}
+            if cfg.enc_dec:
+                extra["frames"] = jax.random.normal(
+                    jax.random.PRNGKey(2), (2, 8, cfg.d_model)) * 0.1
+            logits, cache = jax.jit(make_prefill_step(cfg, S + N))(
+                params, dict(extra, tokens=toks))
+            decode = decode_jit(cfg)
+            for i in range(N):
+                tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+                toks = jnp.concatenate([toks, tok], axis=1)
+                logits, cache = decode(params, cache, tok)
+                full, _ = make_prefill_step(cfg)(
+                    params, dict(extra, tokens=toks))
+                np.testing.assert_allclose(logits, full, atol=1e-4,
+                                           rtol=1e-4)
+            assert int(cache["pos"]) == S + N
+
+        for name, args in %s.items():
+            try:
+                case(*args)
+                out = "OK"
+            except Exception:  # reported per case
+                out = traceback.format_exc()[-2000:]
+            print(json.dumps({name: out}), flush=True)
+    """) % repr(DECODE_CASES)
+    r = run_subprocess_py(code, timeout=900)
+    got = {}
+    for line in r.stdout.splitlines():
+        if line.startswith("{"):
+            got.update(json.loads(line))
+    return got, r.stderr[-3000:]
+
+
+@pytest.mark.parametrize("kind", list(DECODE_CASES))
+def test_decode_matches_full_forward_past_the_prompt(decoded_past_the_prompt,
+                                                     kind):
+    """Decoding beyond the prompt keeps every earlier token its cache
+    kind keeps (a global cache all of them, a ring its window, a chunk
+    its chunk, a recurrent state their sum, an enc-dec layer the encoder's
+    K/V besides): each decode step's logits, from the cache as the donated
+    step writes it in place, equal a full forward pass over the prompt
+    plus the tokens generated so far (float32, own process)."""
+    got, stderr = decoded_past_the_prompt
+    assert got.get(kind) == "OK", got.get(kind) or stderr

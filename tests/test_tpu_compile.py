@@ -12,9 +12,11 @@ these compiles: an entry compiled for a described chip cannot be read back
 without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -128,31 +130,109 @@ def test_gmm_compiles_for_v5e(one_chip, rows, k, n):
              ((64, k, n), jnp.bfloat16), ((64,), jnp.int32))
 
 
-def test_mellum_decode_step_compiles_for_v5e(one_chip, monkeypatch):
-    """The decode step of the chip cell's cut (8 layers, two periods of
-    three sliding layers and a full one) at batch 8 against a 4224-slot
-    global cache, with the grouped matmul on its Pallas path."""
+def _decode_cut(name: str, sharding):
+    """The serve cells' decode cuts as shapes on ``sharding``: mellum2-12b's
+    8 layers (two periods of three sliding layers and a full one) at batch
+    8 against a 4224-slot global cache, granite-20b's 13 layers at batch 8
+    against 2048 + 128 slots.  Returns (cfg, params, cache, tokens)."""
     from repro.models import params as pmod
     from repro.models import transformer
-    from repro.models.steps import make_decode_step
 
-    monkeypatch.setattr(ops, "use_pallas", lambda: True)
-    base = get_arch("mellum2-12b")
-    cfg = base.replace(n_layers=8,
-                       block_groups=((base.block_groups[0][0], 2),))
+    base = get_arch(name)
+    if name == "mellum2-12b":
+        cfg = base.replace(n_layers=8,
+                           block_groups=((base.block_groups[0][0], 2),))
+        slots = 4096 + 128
+    else:
+        cfg = base.replace(n_layers=13, block_groups=((("global",), 13),))
+        slots = 2048 + 128
 
     def shapes(tree):
         return jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=one_chip), tree)
+                                           sharding=sharding), tree)
 
     params = shapes(pmod.abstract(pmod.cast_defs(
         transformer.model_defs(cfg), jnp.bfloat16)))
     cache = shapes(jax.eval_shape(
-        lambda: transformer.init_cache(cfg, 8, 4096 + 128)))
-    tok = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(make_decode_step(cfg)).lower(params, cache,
-                                                    tok).compile()
+        lambda: transformer.init_cache(cfg, 8, slots)))
+    tok = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=sharding)
+    return cfg, params, cache, tok
+
+
+def test_mellum_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The decode step of the chip cell's cut, as ``Server`` jits it, with
+    the grouped matmul on its Pallas path."""
+    from repro.runtime.serve_loop import decode_jit
+
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    cfg, params, cache, tok = _decode_cut("mellum2-12b", one_chip)
+    compiled = decode_jit(cfg).lower(params, cache, tok).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
         >= 3   # gate, up and down of one pattern position at least
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+def _hlo_computations(text: str) -> dict:
+    """{computation name: {instruction name: (result type, opcode,
+    operand names)}, with the root's name under None}."""
+    comps, body = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            body = comps.setdefault(head.group(1), {})
+            continue
+        ins = re.match(r"\s*(ROOT )?%([\w.\-]+) = (.+?) ([a-z][\w\-]*)\((.*)",
+                       line)
+        if body is None or not ins:
+            continue
+        operands = re.findall(r"%([\w.\-]+)", ins.group(5).split(")")[0])
+        calls = re.search(r"calls=%([\w.\-]+)", line)
+        body[ins.group(2)] = (ins.group(3), ins.group(4), operands,
+                              calls.group(1) if calls else None)
+        if ins.group(1):
+            body[None] = ins.group(2)
+    return comps
+
+
+def _writes_one_slot(comps: dict, fused: str, slot: int) -> bool:
+    """Whether computation ``fused`` ends in a dynamic-update-slice of
+    ``slot`` elements into its first operand: a write in place."""
+    body = comps[fused]
+    _, op, operands, _ = body[body[None]]
+    if op != "dynamic-update-slice":
+        return False
+    dims = re.search(r"\[([\d,]*)\]", body[operands[1]][0]).group(1)
+    return int(np.prod([int(d) for d in dims.split(",") if d])) == slot
+
+
+@pytest.mark.parametrize("arch", ["mellum2-12b", "granite-20b"])
+def test_decode_step_writes_its_cache_in_place_for_v5e(one_chip, monkeypatch,
+                                                       arch):
+    """The decode step as ``Server`` jits it aliases its whole K/V cache,
+    and no copy, and no fusion but the one-slot write, has a stacked K/V
+    array as its result: each layer's K and V are read where they lie and
+    written one slot in place."""
+    from repro.runtime.serve_loop import decode_jit
+
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    cfg, params, cache, tok = _decode_cut(arch, one_chip)
+    compiled = decode_jit(cfg).lower(params, cache, tok).compile()
+    mem = compiled.memory_analysis()
+    kv = [x for g in cache["groups"] for ent in g.values()
+          for n, x in ent.items() if n in ("k", "v")]
+    assert mem.alias_size_in_bytes >= sum(x.size * x.dtype.itemsize
+                                          for x in kv)
+    if arch == "mellum2-12b":
+        assert mem.temp_size_in_bytes < 64 * 2**20
+    stacks = {"bf16[%s]" % ",".join(map(str, x.shape)) for x in kv}
+    slot = cfg.n_kv_heads * 8 * cfg.d_head
+    comps = _hlo_computations(compiled.as_text())
+    offending = [
+        (name, op) for body in comps.values()
+        for name, ins in body.items() if name is not None
+        for result, op, _, calls in [ins]
+        if any(s in result for s in stacks)
+        and (op in ("copy", "copy-start")
+             or op == "fusion" and not _writes_one_slot(comps, calls, slot))]
+    assert not offending, offending
